@@ -199,6 +199,8 @@ def main() -> None:
             cur = json.load(f)
     else:
         report_path = tempfile.mktemp(suffix=".json", prefix="bench_gate_")
+        # The sweeps run in child processes, each of which may need the
+        # chip; this parent must never import JAX, or it holds the chip.
         print(f"# bench-gate: running benchmarks -> {report_path}")
         subprocess.run(
             [sys.executable, "-m", "benchmarks.run", "--json", report_path],

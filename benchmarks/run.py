@@ -94,6 +94,8 @@ def main() -> None:
     if args.only and args.only not in BENCHMARKS:
         ap.error(f"unknown benchmark {args.only!r} "
                  f"(choose from {', '.join(sorted(BENCHMARKS))})")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     checks: list[str] = []
     report: dict = {"benchmarks": {}, "checks": []}

@@ -26,7 +26,8 @@ def main() -> None:
     ap.add_argument("--scenario", default="steady")
     args = ap.parse_args()
     # the serving loop lives in the launcher; this example drives it the
-    # way an operator would
+    # way an operator would.  The child needs the chip, so this parent
+    # must never import JAX.
     cmd = [sys.executable, "-m", "repro.launch.serve", "--arch", args.arch,
            "--smoke", "--scenario", args.scenario,
            "--requests", "8", "--slots", "3", "--seed", "0"]

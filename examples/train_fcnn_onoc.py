@@ -1,16 +1,18 @@
-"""End-to-end driver: train the paper's FCNN (reduced NN1) on the synthetic
+"""End-to-end driver: train the paper's FCNN (reduced NN1 by default,
+``--nn NN1..NN6`` for a paper network at full width) on the synthetic
 fashion-mnist-shaped dataset for a few hundred steps, with the per-layer
 parallelism degrees chosen by the ONoC planner and realized as JAX
 shardings.
 
-  PYTHONPATH=src python examples/train_fcnn_onoc.py [--steps 300]
+  PYTHONPATH=src python examples/train_fcnn_onoc.py [--steps 300] [--nn NN1]
 
 With ``--program N`` the planner's schedule is *executed* instead of just
 priced, through the one-call façade ``repro.exec.compile(...)``: the plan
 is compiled to a static RUN/SEND/RECV/FREE period program with residency
 annotations (exec/program.py, schema v2), statically validated and
 cross-checked against core.simulator.simulate_epoch, and interpreted
-under shard_map on an N-device CPU ring (exec/runtime.py).  The default
+under shard_map on a ring of the first N devices of the default backend
+(exec/runtime.py; off-TPU, N forced host CPU devices).  The default
 ``--residency sharded`` keeps each device to ~1/d of the model (its
 column chunks, dropped at the Eq.-11 mirror periods); ``--residency
 replicated`` runs the full-model oracle:
@@ -24,6 +26,8 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.configs.nn_benchmarks import NN_BENCHMARKS  # noqa: E402
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -35,7 +39,11 @@ def main() -> None:
                          "fused Pallas fwd+bwd on TPU, jnp oracle elsewhere)")
     ap.add_argument("--program", type=int, default=0, metavar="N",
                     help="compile the plan to a period program and execute "
-                         "it under shard_map on an N-device CPU ring")
+                         "it under shard_map on a ring of the first N "
+                         "devices")
+    ap.add_argument("--nn", default=None, choices=sorted(NN_BENCHMARKS),
+                    help="paper network at full width (default: NN1 "
+                         "reduced to 784-256-128-10 so CPU runs fast)")
     ap.add_argument("--strategy", default="orrm",
                     choices=["fm", "rrm", "orrm"],
                     help="core mapping strategy (program mode)")
@@ -59,12 +67,14 @@ def main() -> None:
     from repro.core.onoc_model import FCNNWorkload, ONoCConfig
     from repro.core.planner import plan_fcnn
     from repro.data import Batcher, fcnn_classification_dataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import fcnn
     from repro.optim import adam, linear_warmup_cosine
 
+    enable_compile_cache()
     # reduced NN1 (784-1000-500-10 -> 784-256-128-10) so CPU runs fast
-    sizes = [784, 256, 128, 10]
+    sizes = NN_BENCHMARKS[args.nn] if args.nn else [784, 256, 128, 10]
     workload = FCNNWorkload(sizes, batch_size=args.batch)
     onoc = ONoCConfig(m=1000, lambda_max=64)
 
@@ -95,7 +105,7 @@ def main() -> None:
         return params, opt_state, loss
 
     t0 = time.time()
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         for i in range(args.steps):
             batch = next(batches)
             params, opt_state, loss = step(params, opt_state, batch, i)

@@ -75,7 +75,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.exec.program import PeriodProgram
@@ -160,13 +159,13 @@ class ProgramExecutor:
         else:
             body = self._device_program
             pspec = P()
-        self._sharded = shard_map(
+        self._sharded = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pspec, P(), P()), out_specs=P(),
             # loss is replicated by construction (identical full logits on
             # every device after the final gather); collective use below is
             # beyond what the static replication checker can verify.
-            check_rep=False,
+            check_vma=False,
         )
 
     def degrade(self, mode: str = "ref") -> str:

@@ -29,6 +29,11 @@ K/N, minimizing edge padding.  Non-aligned shapes — the paper's 784/10/…
 NN benchmark dims — are zero-padded to block multiples and the result is
 sliced back; zero padding is exact for all three passes (padded rows /
 columns contribute 0 to every contraction and are discarded on output).
+
+Every block is rank 2.  The (N,) bias and db vectors travel as (1, N)
+rows in (1, bn) blocks: Mosaic tiles a rank-1 operand by its full length,
+so a rank-1 (bn,) block of a longer vector is refused on the chip
+whenever the padded width spans more than one block.
 """
 
 from __future__ import annotations
@@ -110,11 +115,6 @@ def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return jnp.pad(x, ((0, rows - r), (0, cols - c)))
 
 
-def _pad1(x: jax.Array, size: int) -> jax.Array:
-    (s,) = x.shape
-    return x if s == size else jnp.pad(x, (0, size - s))
-
-
 # ---------------------------------------------------------------- forward
 
 
@@ -157,7 +157,7 @@ def fcnn_layer(
         raise ValueError(f"unknown activation {activation!r}")
     (bm, bn, bk), (mp, np_, kp) = select_blocks(
         m, k, n, block_m, block_n, block_k)
-    xp, wp, bp = _pad2(x, mp, kp), _pad2(w, kp, np_), _pad1(b, np_)
+    xp, wp, bp = _pad2(x, mp, kp), _pad2(w, kp, np_), _pad2(b.reshape(1, n), 1, np_)
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, k_steps=grid[2], act=activation),
@@ -165,7 +165,7 @@ def fcnn_layer(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
@@ -273,7 +273,7 @@ def _wgrad_kernel(x_ref, dy_ref, y_ref, dw_ref, db_ref, accw_ref, accb_ref,
 
     @pl.when(j_k == 0)
     def _acc_b():
-        accb_ref[...] += jnp.sum(dz, axis=0)
+        accb_ref[...] += jnp.sum(dz, axis=0, keepdims=True)
 
     @pl.when(j_m == m_steps - 1)
     def _finish_w():
@@ -318,16 +318,16 @@ def fcnn_layer_wgrad(
         ],
         out_specs=[
             pl.BlockSpec((bk, bn), lambda jn, jk, jm: (jk, jn)),
-            pl.BlockSpec((bn,), lambda jn, jk, jm: (jn,)),
+            pl.BlockSpec((1, bn), lambda jn, jk, jm: (0, jn)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, np_), x.dtype),
-            jax.ShapeDtypeStruct((np_,), dy.dtype),
+            jax.ShapeDtypeStruct((1, np_), dy.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, bn), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
+            pltpu.VMEM((1, bn), jnp.float32),
         ],
         interpret=interpret,
     )(xp, dyp, yp)
-    return dw[:k, :n], db[:n]
+    return dw[:k, :n], db[0, :n]

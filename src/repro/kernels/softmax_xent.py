@@ -26,8 +26,14 @@ columns are masked to −1e30 inside the forward kernel (a zero-padded
 column would otherwise contribute exp(0) to every row's denominator);
 padded rows compute garbage that is sliced away.
 
-VMEM per step: one (bb, bc) logits tile + three (bb,) fp32 carries —
-for bb=128, bc=512 that is ~260 KB, far inside a v5e core's ~16 MB.
+Per-row vectors (labels, nll, lse, scale and the three carries) travel as
+(B, 1) columns in (bb, 1) blocks.  Mosaic refuses a rank-1 (bb,) block
+unless it spans the whole vector, so rank-1 blocks would compile only
+while one row block is the whole batch.
+
+VMEM per step: one (bb, bc) logits tile + three (bb, 1) fp32 carries,
+each padded to a (bb, 128) lane tile — for bb=128, bc=512 that is
+~450 KB, far inside a v5e core's ~16 MB.
 """
 
 from __future__ import annotations
@@ -42,7 +48,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.fcnn_layer import (
     _LANE,
     _SUBLANE,
-    _pad1,
     _pad2,
     _select_block,
 )
@@ -55,6 +60,11 @@ _DEFAULT_BLOCK_B = 128
 _DEFAULT_BLOCK_C = 512
 
 _NEG_INF = -1e30
+
+
+def _col(v: jax.Array, size: int) -> jax.Array:
+    """A (B,) per-row vector as a zero-padded (size, 1) column."""
+    return _pad2(v.reshape(-1, 1), size, 1)
 
 
 def select_blocks_xent(
@@ -89,15 +99,15 @@ def _fwd_kernel(x_ref, lab_ref, nll_ref, lse_ref, m_ref, l_ref, t_ref,
     # padded class columns must not feed the max/denominator
     x = jnp.where(cols < n_classes, x, _NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1))
+    m_prev = m_ref[...]                                   # (bb, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(jnp.exp(x - m_new[:, None]),
-                                              axis=-1)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(jnp.exp(x - m_new), axis=-1,
+                                              keepdims=True)
     m_ref[...] = m_new
     # the label's logit lives in exactly one tile per row
-    t_ref[...] += jnp.sum(
-        jnp.where(cols == lab_ref[...][:, None], x, 0.0), axis=-1)
+    t_ref[...] += jnp.sum(jnp.where(cols == lab_ref[...], x, 0.0), axis=-1,
+                          keepdims=True)
 
     @pl.when(j == c_steps - 1)
     def _finish():
@@ -124,31 +134,31 @@ def softmax_xent_fwd(
     assert labels.shape == (b,)
     (bb, bc), (bp, cp) = select_blocks_xent(b, c, block_b, block_c)
     xp = _pad2(logits, bp, cp)
-    labp = _pad1(labels, bp)
+    labp = _col(labels, bp)
     grid = (bp // bb, cp // bc)   # class tiles innermost: sequential carry
     nll, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, c_steps=grid[1], n_classes=c),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bp,), jnp.float32),
-            jax.ShapeDtypeStruct((bp,), jnp.float32),
+            jax.ShapeDtypeStruct((bp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bb,), jnp.float32),
-            pltpu.VMEM((bb,), jnp.float32),
-            pltpu.VMEM((bb,), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.float32),
+            pltpu.VMEM((bb, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, labp)
-    return nll[:b], lse[:b]
+    return nll[:b, 0], lse[:b, 0]
 
 
 # --------------------------------------------------------------- backward
@@ -161,10 +171,9 @@ def _bwd_kernel(x_ref, lab_ref, lse_ref, scale_ref, dx_ref):
     x = x_ref[...].astype(jnp.float32)
     bc = x.shape[1]
     cols = j * bc + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    p = jnp.exp(x - lse_ref[...][:, None])
-    onehot = (cols == lab_ref[...][:, None]).astype(jnp.float32)
-    dx_ref[...] = ((p - onehot) * scale_ref[...][:, None]).astype(
-        dx_ref.dtype)
+    p = jnp.exp(x - lse_ref[...])
+    onehot = (cols == lab_ref[...]).astype(jnp.float32)
+    dx_ref[...] = ((p - onehot) * scale_ref[...]).astype(dx_ref.dtype)
 
 
 @functools.partial(
@@ -188,18 +197,16 @@ def softmax_xent_dlogits(
     assert labels.shape == (b,) and lse.shape == (b,) and scale.shape == (b,)
     (bb, bc), (bp, cp) = select_blocks_xent(b, c, block_b, block_c)
     xp = _pad2(logits, bp, cp)
-    labp = _pad1(labels, bp)
-    lsep = _pad1(lse, bp)
-    scalep = _pad1(scale, bp)
+    labp, lsep, scalep = (_col(v, bp) for v in (labels, lse, scale))
     grid = (bp // bb, cp // bc)   # independent tiles, no carry
     out = pl.pallas_call(
         _bwd_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bb, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, cp), logits.dtype),

@@ -235,7 +235,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     from repro.parallel.sharding import use_rules
     from repro.models.layers import use_accum_dtype
 
-    with mesh, use_rules(rules), use_accum_dtype(cfg.accum_dtype):
+    with (jax.sharding.use_abstract_mesh(mesh.abstract_mesh),
+          use_rules(rules), use_accum_dtype(cfg.accum_dtype)):
         if shape.kind == "train":
             settings = settings or steps_lib.TrainSettings()
             step, st_sh, b_sh, state_spec = steps_lib.build_train_step(

@@ -79,7 +79,7 @@ def lower_nn(name: str, batch: int, multi_pod: bool, lambda_max: int = 64,
     jitted = jax.jit(step, in_shardings=(st_sh, b_sh),
                      out_shardings=(st_sh, NamedSharding(mesh, P())),
                      donate_argnums=(0,))
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         lowered = jitted.lower(state_spec, batch_spec)
     return lowered, plan, mesh
 

@@ -77,6 +77,8 @@ def main() -> None:
     sc = sc.replace(prompt_buckets=snap_prompt_buckets(cfg, sc.prompt_buckets))
     trace = make_traffic(sc, args.seed)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     runner = JaxModelRunner(cfg, n_slots=args.slots, max_len=sc.max_len)
     runner.warmup(sc.prompt_buckets)
     autoscaler = ServeAutoscaler(runner.n_devices, args.slots)

@@ -69,7 +69,7 @@ def main() -> None:
         learning_rate=args.lr, microbatches=args.microbatches,
         grad_compression=args.grad_compression)
 
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         step_fn, st_sh, b_sh, _ = steps_lib.build_train_step(
             model, mesh, shape, settings)
         state = steps_lib.init_train_state(model, settings,

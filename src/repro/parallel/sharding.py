@@ -19,7 +19,8 @@ from typing import Any, Mapping, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (
+    AbstractMesh, Mesh, NamedSharding, PartitionSpec as P)
 
 __all__ = [
     "AxisRules",
@@ -145,9 +146,11 @@ def shard_constraint(
     mesh: Mesh | None = None,
     rules: AxisRules | None = None,
 ) -> jax.Array:
-    """with_sharding_constraint by logical names; no-op off-mesh (CPU tests).
+    """with_sharding_constraint by logical names; no-op outside a mesh.
 
-    ``rules`` defaults to the dynamically-scoped active rules (use_rules)."""
+    ``mesh`` defaults to the one scoped by
+    ``jax.sharding.use_abstract_mesh(mesh.abstract_mesh)``; ``rules`` to
+    the dynamically-scoped active rules (use_rules)."""
     mesh = mesh or _current_mesh()
     if mesh is None or mesh.empty:
         return x
@@ -189,9 +192,10 @@ def shard_stacked(tree: Any, mesh: Mesh, axis: str | None = None) -> Any:
     return jax.tree.map(put, tree)
 
 
-def _current_mesh() -> Mesh | None:
-    env = jax._src.mesh.thread_resources.env  # the `with mesh:` context
-    m = env.physical_mesh
+def _current_mesh() -> AbstractMesh | None:
+    """The mesh of the enclosing
+    ``with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):`` block."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

@@ -9,9 +9,12 @@ prices or detects:
   2. transient RUN faults propagate to ``TrainingSupervisor``'s bounded
      retry-with-backoff loop (and, past ``max_retries``, its
      restart-from-checkpoint fallback);
-  3. a kernel failure on the fused path degrades the executor to the jnp
-     reference path (``ProgramExecutor.degrade``) and rebuilds the jitted
-     step — recorded as a ``kernel_fallback`` in the ``FaultReport``;
+  3. an injected ``KernelFault`` on the fused path degrades the executor
+     to the jnp reference path (``ProgramExecutor.degrade``) and rebuilds
+     the jitted step — recorded as a ``kernel_fallback`` in the
+     ``FaultReport``.  Nothing else degrades: a real lowering or compile
+     error propagates, so a kernel the chip's compiler refuses never
+     trains on the reference path unnoticed;
   4. a ``DeviceLossFault`` is fatal to the current mesh: the runner asks
      ``ElasticPlanner.replan_program`` for the Lemma-1 plan on the
      survivors, re-validates and recompiles the period program for the
@@ -52,10 +55,10 @@ from repro.runtime.elastic import ElasticPlanner
 from repro.runtime.fault_tolerance import TrainingSupervisor
 from repro.runtime.faults import (
     DeviceLossFault,
-    FaultError,
     FaultInjector,
     FaultReport,
     FaultSchedule,
+    KernelFault,
 )
 
 __all__ = ["DegradedModeRunner"]
@@ -173,25 +176,20 @@ class DegradedModeRunner:
 
     def _step_fn(self, state: dict, batch: dict) -> tuple[dict, dict]:
         step = int(state["step"])
-        for instr in self.program.instructions:
-            self.injector.instruction_boundary(step, instr)
-        t0 = time.monotonic()
         try:
-            params, opt_state, loss = self._step_jit(
-                state["params"], state["opt_state"], batch, state["step"])
-        except FaultError:
-            raise
-        except Exception:
-            # kernel failure on the fused path: degrade to the reference
-            # path once, rebuild the jitted step, retry.  Already-degraded
-            # executors re-raise (a ref-path failure is a real bug).
+            for instr in self.program.instructions:
+                self.injector.instruction_boundary(step, instr)
+        except KernelFault:
+            # degrade to the reference path once and rebuild the jitted
+            # step; an already-degraded executor re-raises (retryable).
             if self.executor.kernel_mode == "ref":
                 raise
             self.executor.degrade("ref")
             self.report.kernel_fallbacks += 1
             self._step_jit = self._fresh_step()
-            params, opt_state, loss = self._step_jit(
-                state["params"], state["opt_state"], batch, state["step"])
+        t0 = time.monotonic()
+        params, opt_state, loss = self._step_jit(
+            state["params"], state["opt_state"], batch, state["step"])
         self.injector.observe_step(step, time.monotonic() - t0)
         loss_f = float(loss)
         self.losses[step] = loss_f

@@ -24,6 +24,9 @@ Fault taxonomy (``FaultKind``):
                        usable wavelengths => more TDM slots per transition.
   LINK_DEGRADE         a fraction of link capacity is lost: transition
                        drain times inflate by 1/(1-magnitude).
+  KERNEL_FAILURE       the fused kernel path fails at a period's RUN.  The
+                       degraded-mode runner swaps the executor to the jnp
+                       reference path and re-runs the step.
 
 Injection points:
 
@@ -34,7 +37,8 @@ Injection points:
   * ``FaultInjector.instruction_boundary`` — runtime injection: the
     degraded-mode runner walks the compiled program's instruction list
     each step and lets scheduled faults fire at instruction boundaries
-    (raising ``TransientRunFault`` / ``DeviceLossFault``).
+    (raising ``TransientRunFault`` / ``KernelFault`` /
+    ``DeviceLossFault``).
 
 Every fired fault and every recovery action (retry, kernel fallback,
 replan, timeout) is recorded in a structured ``FaultReport`` that
@@ -80,6 +84,7 @@ class FaultKind(str, enum.Enum):
     STRAGGLER = "straggler"
     WAVELENGTH_DEGRADE = "wavelength_degrade"
     LINK_DEGRADE = "link_degrade"
+    KERNEL_FAILURE = "kernel_failure"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -226,7 +231,13 @@ class DeviceLossFault(FaultError):
 
 
 class KernelFault(FaultError):
-    """A kernel path failed; the executor degraded to the reference path."""
+    """The fused kernel path failed — the executor may degrade to the
+    reference path."""
+
+    def __init__(self, step: int, period: int):
+        super().__init__(
+            f"injected kernel failure at step {step}, period {period}")
+        self.step, self.period = step, period
 
 
 @dataclasses.dataclass
@@ -277,8 +288,8 @@ class FaultInjector:
 
     def instruction_boundary(self, step: int, instr) -> None:
         """Called by the runner before each instruction of each step; may
-        raise TransientRunFault / DeviceLossFault.  Period-0 events fire at
-        the first boundary of the step (period-1 RUN)."""
+        raise TransientRunFault / KernelFault / DeviceLossFault.  Period-0
+        events fire at the first boundary of the step (period-1 RUN)."""
         first = instr.period == 1 and getattr(instr.opcode, "value",
                                               instr.opcode) == "run"
         hits = [e for e in self.schedule.at(step)
@@ -293,6 +304,10 @@ class FaultInjector:
                     self.report.retries += 1
                     self.report.record(e)
                     raise TransientRunFault(step, instr.period, e.device)
+            elif e.kind is FaultKind.KERNEL_FAILURE:
+                if self._fires(e):
+                    self.report.record(e)
+                    raise KernelFault(step, instr.period)
             elif e.kind is FaultKind.STRAGGLER:
                 if self._fires(e):
                     self.report.straggles += 1

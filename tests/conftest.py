@@ -1,5 +1,4 @@
 import os
-import sys
 
 # Tests run on host CPU devices — the dry-run (and only the dry-run)
 # forces 512 devices via its own XLA_FLAGS before jax init.
@@ -14,20 +13,7 @@ if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count=8 {_flags}".strip())
 
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:
-    # The runtime image ships without hypothesis.  Install the deterministic
-    # stub (tests/_hypothesis_stub.py) under both module names so the
-    # property-test modules still collect and run their checks with a fixed
-    # sample budget instead of erroring out the whole session.
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub as _stub
-
-    sys.modules.setdefault("hypothesis", _stub)
-    sys.modules.setdefault("hypothesis.strategies", _stub)
-    _stub.strategies = _stub
-    settings = _stub.settings
+from hypothesis import settings  # noqa: E402
 
 settings.register_profile("ci", max_examples=25, deadline=None)
 settings.load_profile("ci")

@@ -17,7 +17,12 @@ from repro.data import Batcher, fcnn_classification_dataset
 from repro.models import fcnn
 from repro.optim import adam
 from repro.runtime.degraded import DegradedModeRunner
-from repro.runtime.faults import FaultEvent, FaultKind, FaultSchedule
+from repro.runtime.faults import (
+    FaultError,
+    FaultEvent,
+    FaultKind,
+    FaultSchedule,
+)
 
 SIZES = [32, 16, 8, 10]
 BATCH = 8
@@ -110,17 +115,30 @@ def test_transient_run_fault_is_retried_not_fatal():
 
 
 def test_kernel_failure_degrades_to_ref_path():
-    """kernel_mode="pallas" cannot lower on CPU: the runner must fall back
-    to the reference path once and finish training."""
-    runner, state, _, report = _run(FaultSchedule(), N_DEV,
-                                    kernel_mode="pallas", n_steps=3)
+    """An injected KernelFault on the fused path: the runner falls back to
+    the reference path once and finishes training."""
+    sched = FaultSchedule(events=(
+        FaultEvent(kind=FaultKind.KERNEL_FAILURE, step=0, period=1),))
+    runner, state, _, report = _run(sched, N_DEV,
+                                    kernel_mode="pallas_interpret",
+                                    n_steps=3)
     assert report.kernel_fallbacks == 1
+    assert [f["kind"] for f in report.fired] == ["kernel_failure"]
     assert runner.executor.kernel_mode == "ref"
     assert int(state["step"]) == 3
     scratch, _, _, _ = _run(FaultSchedule(), N_DEV, n_steps=3)
     for s in range(3):
         np.testing.assert_allclose(runner.losses[s], scratch.losses[s],
                                    rtol=1e-6, atol=1e-7)
+
+
+def test_kernel_compile_error_propagates():
+    """A real error from the step — here kernel_mode="pallas", which cannot
+    lower on CPU — is not a KernelFault: it propagates instead of training
+    on the reference path."""
+    with pytest.raises(Exception) as info:
+        _run(FaultSchedule(), N_DEV, kernel_mode="pallas", n_steps=1)
+    assert not isinstance(info.value, FaultError)
 
 
 def test_sharded_residency_recovery_matches_replicated():

@@ -51,7 +51,7 @@ def test_lm_train_step_decreases_loss():
     mesh = make_host_mesh()
     shape = ShapeSpec("t", 32, 4, "train")
     settings = steps_lib.TrainSettings(learning_rate=1e-3)
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         step, st_sh, _, _ = steps_lib.build_train_step(model, mesh, shape,
                                                        settings)
         state = jax.device_put(
@@ -75,7 +75,7 @@ def test_int8_compression_still_learns():
     shape = ShapeSpec("t", 32, 4, "train")
     settings = steps_lib.TrainSettings(learning_rate=1e-3,
                                        grad_compression="int8")
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         step, st_sh, _, _ = steps_lib.build_train_step(model, mesh, shape,
                                                        settings)
         state = jax.device_put(
@@ -98,7 +98,7 @@ def test_microbatched_step_matches_shapes():
     mesh = make_host_mesh()
     shape = ShapeSpec("t", 16, 8, "train")
     settings = steps_lib.TrainSettings(microbatches=2)
-    with mesh:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         step, st_sh, _, _ = steps_lib.build_train_step(model, mesh, shape,
                                                        settings)
         state = jax.device_put(

@@ -85,7 +85,7 @@ def test_flags_np_random_in_jitted_body():
 def test_flags_np_random_in_shard_map_target():
     (v,) = _lint("""
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x):
             return x * np.random.rand()
