@@ -45,7 +45,6 @@ from benchmarks import (  # noqa: E402
     table7_prediction,
     table8_9_baselines,
     table10_optimal_cores,
-    roofline_report,
 )
 
 BENCHMARKS = {
@@ -55,7 +54,6 @@ BENCHMARKS = {
     "fig7_percore_sweep": fig7_percore_sweep.run,
     "fig10_onoc_vs_enoc": fig10_onoc_vs_enoc.run,
     "strategy_analysis": strategy_analysis.run,
-    "roofline_report": roofline_report.run,
     "fcnn_kernel_microbench": fcnn_kernel_microbench.run,
     "softmax_xent_microbench": fcnn_kernel_microbench.run_softmax_xent,
     "exec_program_bench": exec_program_bench.run,

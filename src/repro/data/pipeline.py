@@ -23,6 +23,8 @@ import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
+
 
 def fcnn_classification_dataset(
     n_samples: int, input_dim: int = 784, n_classes: int = 10, seed: int = 0,
@@ -74,15 +76,14 @@ class Batcher:
         start = (self.step * self.batch_size) % n
         idx = (np.arange(self.batch_size) + start) % n
         self.step += 1
-        out = {}
-        for k, v in self.data.items():
-            b = v[idx]
-            if self.mesh is not None:
-                out[k] = jax.device_put(
-                    b, NamedSharding(self.mesh, self._spec(b)))
-            else:
-                out[k] = jnp.asarray(b)
-        return out
+        with obs.span(obs.BATCH_INDEX):
+            rows = {k: v[idx] for k, v in self.data.items()}
+        with obs.span(obs.BATCH_PUT):
+            if self.mesh is None:
+                return {k: jnp.asarray(b) for k, b in rows.items()}
+            return {k: jax.device_put(
+                        b, NamedSharding(self.mesh, self._spec(b)))
+                    for k, b in rows.items()}
 
     # --- checkpointable state ---
     def state(self) -> dict:

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.core.allocation import MappingStrategy
 from repro.core.onoc_model import FCNNWorkload, ONoCConfig
 from repro.core.planner import FCNNPlan, plan_fcnn, ring_mesh_axes
@@ -145,18 +146,21 @@ class Executable:
         ex = self.executor
 
         def step(state, batch):
+            obs.bump(obs.TRAIN_STEP_TRACES)   # once per trace, not per call
             loss, grads = jax.value_and_grad(ex.loss_fn)(state["params"],
                                                          batch)
-            if grad_clip is not None:
-                grads, gnorm = clip_by_global_norm(grads, grad_clip)
-            else:
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)))
-            params, opt = optimizer.update(grads, state["opt"],
-                                           state["params"], state["step"])
-            new_state = {"params": params, "opt": opt,
-                         "step": state["step"] + 1}
+            with jax.named_scope(obs.GRAD_NORM):
+                if grad_clip is not None:
+                    grads, gnorm = clip_by_global_norm(grads, grad_clip)
+                else:
+                    gnorm = jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(grads)))
+            with jax.named_scope(obs.OPTIMIZER):
+                params, opt = optimizer.update(
+                    grads, state["opt"], state["params"], state["step"])
+                count = state["step"] + 1
+            new_state = {"params": params, "opt": opt, "step": count}
             return new_state, {"loss": loss, "grad_norm": gnorm}
 
         return jax.jit(step, donate_argnums=(0,) if donate else ())

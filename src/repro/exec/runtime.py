@@ -77,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.exec.program import PeriodProgram
 from repro.kernels import ops
 from repro.optim.optimizers import Optimizer
@@ -190,21 +191,26 @@ class ProgramExecutor:
         batch = x.shape[0]
         for lay in self._layout:
             lp = params["layers"][lay.layer - 1]
-            # RUN: this device's column chunk of W/b (freed devices shadow
-            # the window head's chunk; their result is never selected).
-            chunk = jnp.asarray(lay.owner_chunk)[me]
-            w_loc = jax.lax.dynamic_slice_in_dim(
-                lp["w"], chunk * lay.width, lay.width, axis=1)
-            b_loc = jax.lax.dynamic_slice_in_dim(
-                lp["b"], chunk * lay.width, lay.width, axis=0)
-            y_loc = ops.fcnn_layer(h, w_loc, b_loc, lay.activation,
-                                   force=self.kernel_mode)
-            # SEND/RECV (or the period-l turnaround gather): one collective;
-            # chunk j of the next activation comes from device window[j].
-            gathered = jax.lax.all_gather(y_loc, self.axis)   # (n, B, width)
-            h = jnp.moveaxis(gathered[lay.window], 0, 1)      # (B, d, width)
-            h = h.reshape(batch, lay.n_out)
-        return ops.softmax_xent(h, y, force=self.kernel_mode)
+            with jax.named_scope(obs.run_scope(lay.layer)):
+                # RUN: this device's column chunk of W/b (freed devices
+                # shadow the window head's chunk; their result is never
+                # selected).
+                chunk = jnp.asarray(lay.owner_chunk)[me]
+                w_loc = jax.lax.dynamic_slice_in_dim(
+                    lp["w"], chunk * lay.width, lay.width, axis=1)
+                b_loc = jax.lax.dynamic_slice_in_dim(
+                    lp["b"], chunk * lay.width, lay.width, axis=0)
+                y_loc = ops.fcnn_layer(h, w_loc, b_loc, lay.activation,
+                                       force=self.kernel_mode)
+            with jax.named_scope(obs.xfer_scope(lay.layer)):
+                # SEND/RECV (or the period-l turnaround gather): one
+                # collective; chunk j of the next activation comes from
+                # device window[j].
+                gathered = jax.lax.all_gather(y_loc, self.axis)
+                h = jnp.moveaxis(gathered[lay.window], 0, 1)  # (B, d, width)
+                h = h.reshape(batch, lay.n_out)
+        with jax.named_scope(obs.LOSS):
+            return ops.softmax_xent(h, y, force=self.kernel_mode)
 
     def _device_program_sharded(self, params: Params, x: jax.Array,
                                 y: jax.Array) -> jax.Array:
@@ -216,17 +222,21 @@ class ProgramExecutor:
         batch = x.shape[0]
         for lay in self._layout:
             lp = params["layers"][lay.layer - 1]
-            w_loc = lp["w"][0]                    # (n_in, width) chunk
-            b_loc = lp["b"][0]                    # (width,)
-            y_loc = ops.fcnn_layer(h, w_loc, b_loc, lay.activation,
-                                   force=self.kernel_mode)
-            # Same window-ordered selection as the oracle: chunk j of the
-            # next activation comes from device window[j], whose stacked
-            # slot holds exactly chunk j (shard_params' placement).
-            gathered = jax.lax.all_gather(y_loc, self.axis)   # (n, B, width)
-            h = jnp.moveaxis(gathered[lay.window], 0, 1)      # (B, d, width)
-            h = h.reshape(batch, lay.n_out)
-        return ops.softmax_xent(h, y, force=self.kernel_mode)
+            with jax.named_scope(obs.run_scope(lay.layer)):
+                w_loc = lp["w"][0]                # (n_in, width) chunk
+                b_loc = lp["b"][0]                # (width,)
+                y_loc = ops.fcnn_layer(h, w_loc, b_loc, lay.activation,
+                                       force=self.kernel_mode)
+            with jax.named_scope(obs.xfer_scope(lay.layer)):
+                # Same window-ordered selection as the oracle: chunk j of
+                # the next activation comes from device window[j], whose
+                # stacked slot holds exactly chunk j (shard_params'
+                # placement).
+                gathered = jax.lax.all_gather(y_loc, self.axis)
+                h = jnp.moveaxis(gathered[lay.window], 0, 1)  # (B, d, width)
+                h = h.reshape(batch, lay.n_out)
+        with jax.named_scope(obs.LOSS):
+            return ops.softmax_xent(h, y, force=self.kernel_mode)
 
     # ------------------------------------------------------- sharded layout
 

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels.ref import act_deriv_from_output
 
 __all__ = [
@@ -112,7 +113,8 @@ def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
     r, c = x.shape
     if r == rows and c == cols:
         return x
-    return jnp.pad(x, ((0, rows - r), (0, cols - c)))
+    with jax.named_scope(obs.PAD):
+        return jnp.pad(x, ((0, rows - r), (0, cols - c)))
 
 
 # ---------------------------------------------------------------- forward
@@ -171,8 +173,10 @@ def fcnn_layer(
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="fcnn_layer",
     )(xp, wp, bp)
-    return out[:m, :n]
+    with jax.named_scope(obs.PAD):
+        return out[:m, :n]
 
 
 # ------------------------------------------------------------------ dgrad
@@ -235,8 +239,10 @@ def fcnn_layer_dgrad(
         out_shape=jax.ShapeDtypeStruct((mp, kp), dy.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
+        name="fcnn_layer_dgrad",
     )(dyp, yp, wp)
-    return out[:m, :k]
+    with jax.named_scope(obs.PAD):
+        return out[:m, :k]
 
 
 # ------------------------------------------------------------------ wgrad
@@ -329,5 +335,7 @@ def fcnn_layer_wgrad(
             pltpu.VMEM((1, bn), jnp.float32),
         ],
         interpret=interpret,
+        name="fcnn_layer_wgrad",
     )(xp, dyp, yp)
-    return dw[:k, :n], db[0, :n]
+    with jax.named_scope(obs.PAD):
+        return dw[:k, :n], db[0, :n]

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels.fcnn_layer import (
     _LANE,
     _SUBLANE,
@@ -157,8 +158,10 @@ def softmax_xent_fwd(
             pltpu.VMEM((bb, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="softmax_xent_fwd",
     )(xp, labp)
-    return nll[:b, 0], lse[:b, 0]
+    with jax.named_scope(obs.PAD):
+        return nll[:b, 0], lse[:b, 0]
 
 
 # --------------------------------------------------------------- backward
@@ -211,5 +214,7 @@ def softmax_xent_dlogits(
         out_specs=pl.BlockSpec((bb, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, cp), logits.dtype),
         interpret=interpret,
+        name="softmax_xent_dlogits",
     )(xp, labp, lsep, scalep)
-    return out[:b, :c]
+    with jax.named_scope(obs.PAD):
+        return out[:b, :c]

@@ -23,6 +23,7 @@ from jax.sharding import (
 )
 
 import repro.exec as rexec
+from hlo_scopes import REQUIRED, present, unscoped
 from repro.configs.nn_benchmarks import NN_BENCHMARKS, onoc_config, workload
 from repro.kernels.fcnn_layer import (
     fcnn_layer,
@@ -152,7 +153,11 @@ def _train_step_text(name: str, devices, batch: int = 128) -> str:
 
 @pytest.mark.parametrize("name", ["NN1", "NN6"])
 def test_train_step_compiles_on_one_chip(topo, name):
-    _train_step_text(name, topo.devices[:1])
+    text = _train_step_text(name, topo.devices[:1])
+    # every instruction names a program scope (repro.obs); on one chip a
+    # transition moves nothing, so no fp<i>.xfer op is left
+    assert unscoped(text) == []
+    assert REQUIRED - {"fp1.xfer"} <= present(text)
 
 
 @pytest.mark.parametrize("name", ["NN1", "NN6"])
@@ -160,3 +165,5 @@ def test_sharded_train_step_compiles_on_four_chips(topo, name):
     assert len(topo.devices) == 4
     text = _train_step_text(name, topo.devices)
     assert "all-gather" in text   # the inter-period activation broadcast
+    assert unscoped(text) == []
+    assert REQUIRED <= present(text)
