@@ -9,10 +9,14 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels import ref as R
+from repro.configs.nn_benchmarks import NN_BENCHMARKS
 from repro.kernels.fcnn_layer import (
+    fcnn_layer,
     fcnn_layer_dgrad,
     fcnn_layer_wgrad,
     select_blocks,
+    vmem_bytes,
+    vmem_budget,
 )
 
 RNG = np.random.default_rng(7)
@@ -110,6 +114,35 @@ def test_fcnn_backward_kernels_match_oracles(act):
                                rtol=5e-6, atol=5e-6)
     np.testing.assert_allclose(np.asarray(db), np.asarray(db_ref),
                                rtol=5e-6, atol=5e-6)
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dgrad", "wgrad"])
+def test_fcnn_kernels_multiblock_defaults(kind):
+    """Each pass at its automatic blocks, on a ragged shape that they split
+    into more than one block on every grid axis: the contraction
+    accumulates over several steps, and wgrad's db sums row blocks on the
+    K==0 slice only."""
+    m, k, n = 1030, 1150, 1100
+    (bm, bn, bk), (mp, np_, kp) = select_blocks(m, k, n, kind=kind)
+    assert min(mp // bm, np_ // bn, kp // bk) > 1
+    act = "sigmoid"
+    x = _arr((m, k), jnp.float32)
+    w = _arr((k, n), jnp.float32, 0.05)
+    b = _arr((n,), jnp.float32)
+    y = R.fcnn_layer_ref(x, w, b, act)
+    dy = _arr((m, n), jnp.float32)
+    if kind == "fwd":
+        got = [fcnn_layer(x, w, b, act, interpret=True)]
+        want = [y]
+    elif kind == "dgrad":
+        got = [fcnn_layer_dgrad(dy, y, w, act, interpret=True)]
+        want = [R.fcnn_layer_dgrad_ref(dy, y, w, act)]
+    else:
+        got = fcnn_layer_wgrad(x, dy, y, act, interpret=True)
+        want = R.fcnn_layer_wgrad_ref(x, dy, y, act)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=5e-6, atol=5e-5)
 
 
 def test_fcnn_grad_through_model_loss():
@@ -214,6 +247,34 @@ def test_select_blocks_minimizes_padding():
     assert mp % bm == 0 and np_ % bn == 0 and kp % bk == 0
     assert mp - 784 < bm and kp - 784 < 128 + bk  # minimal edge padding
     assert np_ == 128  # 10 -> one lane tile
+
+
+def _rule_shapes(name):
+    """(n_in, n_out) of every layer of paper network ``name``, and of each
+    layer's column chunk on a 2- or 4-chip ring (n_out / d)."""
+    sizes = NN_BENCHMARKS[name]
+    layers = list(zip(sizes[:-1], sizes[1:]))
+    return layers + [(k, n // d) for k, n in layers for d in (2, 4)
+                     if n % d == 0]
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dgrad", "wgrad"])
+@pytest.mark.parametrize("batch", [64, 128, 1024])
+@pytest.mark.parametrize("name", sorted(NN_BENCHMARKS))
+def test_select_blocks_rule(name, batch, kind):
+    """Automatic blocks keep the least edge padding (sublane 8 for M, lane
+    128 for K and N, as the 128/128/512 blocks gave), divide their padded
+    dims and fit the VMEM budget the rule selects under."""
+    def least(v, unit):
+        return -(-v // unit) * unit
+
+    for k, n in _rule_shapes(name):
+        (bm, bn, bk), (mp, np_, kp) = select_blocks(batch, k, n, kind=kind)
+        assert (mp, np_, kp) == (least(batch, 8), least(n, 128),
+                                 least(k, 128)), (k, n)
+        assert mp % bm == 0 and np_ % bn == 0 and kp % bk == 0, (k, n)
+        assert bm % 8 == 0 and bn % 128 == 0 and bk % 128 == 0, (k, n)
+        assert vmem_bytes(kind, bm, bn, bk) <= vmem_budget(), (k, n)
 
 
 @pytest.mark.parametrize("b,h,s,d,bq", [

@@ -10,6 +10,7 @@ at a time may load the TPU library, so it is never described at import.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +74,27 @@ def _sds(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*?)\)")
+
+
+def _fcnn_values_in_vmem(text: str) -> list[str]:
+    """``fcnn_layer*`` kernel calls of compiled HLO text with an operand or
+    a result that XLA placed in VMEM (memory space ``S(1)``)."""
+    shapes, calls = {}, []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op, operands = m.groups()
+        shapes[name] = shape
+        if op == "custom-call" and name.startswith("fcnn_layer"):
+            calls.append((name, shape, operands))
+    return [name for name, shape, operands in calls
+            if "S(1)" in shape or any(
+                "S(1)" in shapes.get(o.strip().lstrip("%"), "")
+                for o in operands.split(","))]
+
+
 def _compiled_text(lowered) -> str:
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text   # the Pallas kernel is in the program
@@ -82,7 +104,7 @@ def _compiled_text(lowered) -> str:
 # ---------------------------------------------------------------- kernels
 
 
-@pytest.mark.parametrize("batch", [64, 128])
+@pytest.mark.parametrize("batch", [64, 128, 1024])
 @pytest.mark.parametrize("n_in,n_out", LAYER_SHAPES)
 @pytest.mark.parametrize("kernel", ["fwd", "dgrad", "wgrad"])
 def test_fcnn_layer_compiles(one_chip, kernel, n_in, n_out, batch):
@@ -158,6 +180,8 @@ def test_train_step_compiles_on_one_chip(topo, name):
     # transition moves nothing, so no fp<i>.xfer op is left
     assert unscoped(text) == []
     assert REQUIRED - {"fp1.xfer"} <= present(text)
+    # the kernels stream their own operands and results from and to HBM
+    assert _fcnn_values_in_vmem(text) == []
 
 
 @pytest.mark.parametrize("name", ["NN1", "NN6"])
@@ -167,3 +191,4 @@ def test_sharded_train_step_compiles_on_four_chips(topo, name):
     assert "all-gather" in text   # the inter-period activation broadcast
     assert unscoped(text) == []
     assert REQUIRED <= present(text)
+    assert _fcnn_values_in_vmem(text) == []
