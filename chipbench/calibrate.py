@@ -6,14 +6,15 @@ For each seed, in one process that holds the chips:
 
 * ``program`` — the timed step's first three steps against the reference
   (the lower reading of each compared number);
-* ``control`` — the reference in bfloat16, one precision below the
-  configuration's float32, in the program's place;
+* ``control`` — the reference one precision below the configuration's
+  (bfloat16 for float32), in the program's place;
 * ``half_batch``, ``altered_answer`` — faults planted in the reference put
   in the program's place;
 * ``no_exchange`` — the program with its all-gather transitions replaced
   by each chip's own chunk (cells on more than one chip).
 
-A step that returns its state unchanged reads 1 on ``update_norm_gap`` by
+The program and the reference are the kind's (``kinds/<kind>.py``).  A
+step that returns its state unchanged reads 1 on ``update_norm_gap`` by
 construction and needs no run.
 
     python3 chipbench/calibrate.py --workload nn6.b128 --seeds 1-12 \
@@ -33,8 +34,7 @@ import time  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from chipbench import checks, harness, spec, traffic  # noqa: E402
-from repro.data import Batcher  # noqa: E402
+from chipbench import checks, harness, spec  # noqa: E402
 
 WHATS = ("program", "control", "half_batch", "altered_answer", "no_exchange")
 
@@ -59,52 +59,40 @@ def readings(cell, devices, seeds, what, kernel_mode="pallas",
              detail=False):
     """One row of compared numbers per seed, for each reading in
     ``what`` (with ``detail``, also each side's per-leaf norms)."""
-    reference = spec.load_reference(cell)
-    opt_conf = cell.config["optimizer"]
-    exe, opt, _ = harness.build(cell, devices, kernel_mode)
-    steps = {"program": (exe, exe.train_step(opt))}
+    kind = spec.load_kind(cell)
+    programs = {"program": kind.build(cell, devices, kernel_mode)}
     if "no_exchange" in what:
-        # its own executable, so that the planted fault is traced afresh
-        broken, _, _ = harness.build(cell, devices, kernel_mode)
-        steps["no_exchange"] = (broken, broken.train_step(opt))
+        # its own program, so that the planted fault is traced afresh
+        programs["no_exchange"] = kind.build(cell, devices, kernel_mode)
 
-    def program(name, key, x, y):
-        ex, step = steps[name]
-        state = ex.init_state(key, opt)
-        batcher = Batcher({"x": x, "y": y}, batch_size=cell.batch,
-                          mesh=ex.mesh)
-        _, got = harness.drive_first_steps(step, state, batcher,
-                                           opt_conf["b1"])
+    def program(name, seed, data):
+        prog, step = programs[name]
+        state = kind.init_state(cell, prog, seed)
+        _, got = kind.first_steps(cell, step, state,
+                                  kind.feed(cell, prog, data))
         return got
 
     rows = []
     for seed in seeds:
         t = time.perf_counter()
-        x, y = traffic.dataset(seed, cell.traffic, cell.sizes[0])
-        first = traffic.step_rows(x, y, cell.batch, harness.CHECK_STEPS)
-        key = traffic.weights_key(seed)
-        ref = reference.train(key, cell.sizes, first, opt_conf,
-                              device=devices[0])
+        data = kind.dataset(cell, seed)
+        ref = kind.reference(cell, seed, data, devices[0])
         row = {"seed": seed, "ref_losses": list(ref.losses)}
         if detail:
             row["ref_grad_norms"] = ref.grad_norms
             row["ref_update_norms"] = ref.update_norms
         for w in what:
             if w == "program":
-                got = program(w, key, x, y)
+                got = program(w, seed, data)
             elif w == "no_exchange":
                 gather = jax.lax.all_gather
                 jax.lax.all_gather = _no_exchange(len(devices))
                 try:
-                    got = program(w, key, x, y)
+                    got = program(w, seed, data)
                 finally:
                     jax.lax.all_gather = gather
-            elif w == "control":
-                got = reference.train(key, cell.sizes, first, opt_conf,
-                                      dtype="bfloat16", device=devices[0])
             else:
-                got = reference.train(key, cell.sizes, first, opt_conf,
-                                      fault=w, device=devices[0])
+                got = kind.reference(cell, seed, data, devices[0], planted=w)
             row[w] = checks.compare(got, ref)
             row[f"{w}_losses"] = list(got.losses)
             if detail:

@@ -1,14 +1,14 @@
 """The comparison that decides ``correct`` for a training cell.
 
-Set-up drives the timed step through its first three steps; the plain
-reference follows the same three steps from the same seed.  The numbers
-below are computed in every run; each cell's file gives limits to those it
-compares:
+Set-up drives the timed step through its first ``CHECK_STEPS`` steps; the
+plain reference follows the same steps from the same seed, and both name
+the parameter leaves alike.  The numbers below are computed in every run;
+each cell's file gives limits to those it compares:
 
 * ``loss_gap`` — the largest relative gap of the three steps' losses;
   ``first_loss_gap`` — the first step's alone.
 * ``grad_norm_gap`` — the norm of the first gradient as the optimizer got
-  it (worked out from Adam's first moment after one step), by the worst
+  it (worked out from the optimizer's state after one step), by the worst
   leaf: |‖g_prog‖ − ‖g_ref‖| over the larger of the reference leaf's norm
   and the median leaf's.
 * ``update_norm_gap`` — the same for the parameters' change over the
@@ -29,6 +29,7 @@ import math
 import statistics
 from typing import Sequence
 
+CHECK_STEPS = 3
 NAMES = ("loss_gap", "first_loss_gap", "grad_norm_gap", "update_norm_gap",
          "update_median_gap")
 STILL = 1e-3
@@ -41,10 +42,6 @@ class Readings:
     losses: tuple[float, ...]
     grad_norms: dict[str, float]
     update_norms: dict[str, float]
-
-
-def leaf_names(n_layers: int) -> list[str]:
-    return [f"{kind}{i}" for i in range(1, n_layers + 1) for kind in "wb"]
 
 
 def _worst(values: Sequence[float]) -> float:

@@ -1,21 +1,43 @@
 """Run one training cell on the chip and build its result line.
 
-The timed path is the one users call:
+The timed path is the one users call, built by the configuration's model
+kind, ``kinds/<kind>.py`` (``"kind"`` in the configuration's file).  Set-up
+builds one step and state, drives them through the first
+``checks.CHECK_STEPS`` steps (whose readings decide ``correct``), warms up,
+and hands the same objects to the window.  The window dispatches steps
+with no sync until ``--seconds`` have passed and ends in
+``block_until_ready``.  With ``--trace 1`` a traced window of the cell's
+fixed step count follows, with host spans ``batch``, ``dispatch`` and
+``wait``; its profile is loaded once the traced steps end, for the
+per-layer readers.  The plain reference runs last, after the program's
+state is freed.
 
-    exe = repro.exec.compile(FCNNWorkload(sizes, batch), onoc_config(),
-                             Mesh(devices[:chips], ("cores",)),
-                             strategy=..., residency=...)
-    step = exe.train_step(adam(lr))
-    for batch in Batcher(dataset, batch_size=batch, mesh=exe.mesh):
-        state, metrics = step(state, batch)
+A kind is a module of these functions (``cell`` is a ``spec.Cell``):
 
-Set-up builds that one step and state, drives them through the first three
-steps (whose readings decide ``correct``), warms up, and hands the same
-objects to the window.  The window dispatches steps with no sync until
-``--seconds`` have passed and ends in ``block_until_ready``.  With
-``--trace 1`` a traced window of the cell's fixed step count follows, with
-host spans ``batch``, ``dispatch`` and ``wait``.  The plain reference runs
-last, after the program's state is freed.
+    dataset(cell, seed) -> data
+        every input of the run, made from the seed, opaque to the harness
+    build(cell, devices, kernel_mode) -> (program, step)
+        the compiled program, checked against what the configuration and
+        the cell state, and its jitted ``step(state, batch) -> (state,
+        metrics)``, where ``metrics["loss"]`` is the step's loss
+    init_state(cell, program, seed) -> state
+        any pytree, made on the devices from the seed
+    feed(cell, program, data) -> iterator of batches
+    first_steps(cell, step, state, feed) -> (state, checks.Readings)
+        the first ``checks.CHECK_STEPS`` steps through ``step`` and
+        ``feed``: their losses, the first gradient by leaf (from the
+        kind's own optimizer state) and each leaf's change, the leaves
+        named as the reference names them (e.g. from their tree paths)
+    reference(cell, seed, data, device, planted=None) -> checks.Readings
+        the kind's plain reference over the same steps; ``planted`` puts
+        the control (``"control"``) or a fault in the program's place
+    samples_per_step(cell) -> int
+        samples (rows, sequences) that one step trains on
+    flops_per_sample(cell) -> float
+        model operations a sample, for ``mfu``
+    kernel_calls(cell) -> list[work.Call]
+        the required work of one step's kernel calls, for
+        ``kernel_roofline``
 """
 
 from __future__ import annotations
@@ -31,19 +53,10 @@ from pathlib import Path
 from typing import Any
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh
 
-import repro.exec as rexec
-from chipbench import checks, spec, traffic
+from chipbench import checks, scopes, spec
 from chipbench import trace as tracing
-from repro.configs.nn_benchmarks import onoc_config
-from repro.core.onoc_model import FCNNWorkload
-from repro.data import Batcher
-from repro.optim import adam
 
-CHECK_STEPS = 3
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
@@ -96,87 +109,19 @@ def peaks(kind: str, pkg: Path) -> dict[str, Any]:
     return table[kind]
 
 
-def _flat(params: dict) -> list:
-    return [layer[kind] for layer in params["layers"] for kind in ("w", "b")]
-
-
-@jax.jit
-def _norms(leaves, scale):
-    return jnp.stack([jnp.linalg.norm(a.astype(jnp.float32).ravel())
-                      for a in leaves]) * scale
-
-
-@jax.jit
-def _change_norms(new, old):
-    return jnp.stack([jnp.linalg.norm((a - b).astype(jnp.float32).ravel())
-                      for a, b in zip(new, old)])
-
-
-_copy = jax.jit(lambda leaves: [jnp.copy(a) for a in leaves])
-
-
-def _named(params: dict, values) -> dict[str, float]:
-    names = checks.leaf_names(len(params["layers"]))
-    return dict(zip(names, (float(v) for v in jax.device_get(values))))
-
-
-def drive_first_steps(step, state, batcher, b1: float):
-    """Take the first CHECK_STEPS steps through the window's own step and
-    feed; return (state, readings).  The program's params are in its
-    stacked chunk layout, whose off-window chunks are zeros, so a leaf's
-    norm is the full matrix's."""
-    p0 = _copy(_flat(state["params"]))
-    losses, grad_norms = [], None
-    for t in range(CHECK_STEPS):
-        state, metrics = step(state, next(batcher))
-        losses.append(metrics["loss"])
-        if t == 0:
-            # Adam's first moment after one step is (1 - b1) * g; read it
-            # before the next step takes the state.
-            grad_norms = _named(state["params"], _norms(
-                _flat(state["opt"]["m"]), jnp.float32(1.0 / (1.0 - b1))))
-    update_norms = _named(state["params"],
-                          _change_norms(_flat(state["params"]), p0))
-    del p0
-    readings = checks.Readings(
-        tuple(float(v) for v in jax.device_get(losses)), grad_norms,
-        update_norms)
-    return state, readings
-
-
 @dataclasses.dataclass(frozen=True)
 class Context:
     """What a per-layer metric reader may read."""
 
     cell: spec.Cell
-    degrees: list[int]
+    flops_per_sample: float       # the kind's model operations a sample
+    kernel_calls: list            # the kind's ``work.Call``s of one step
     steps: int                    # traced steps
     host: dict[str, list[float]]  # host seconds per traced step, by span
     summary: tracing.Summary
+    profile: scopes.Profile       # the traced window, with program scopes
     peak: dict[str, Any]
     samples_per_s: float
-
-
-def build(cell: spec.Cell, devices: list, kernel_mode: str = "pallas"):
-    """(executable, optimizer) for the cell, checked against what its
-    configuration states."""
-    conf = cell.config
-    if conf["dtype"] != "float32" or conf["matmul_precision"] != "default":
-        raise ValueError(f"{cell.config_name}: the harness runs float32 at "
-                         f"default matmul precision")
-    exe = rexec.compile(FCNNWorkload(cell.sizes, batch_size=cell.batch),
-                        onoc_config(), Mesh(np.asarray(devices), ("cores",)),
-                        strategy=conf["strategy"], residency=conf["residency"])
-    if exe.kernel_mode != kernel_mode:
-        raise SystemExit(f"chipbench: dispatch chose kernel mode "
-                         f"{exe.kernel_mode!r}, not {kernel_mode!r}")
-    degrees = [len(r.devices) for r in exe.program.runs(phase="fp")]
-    if degrees != cell.params["fp_degrees"]:
-        raise SystemExit(f"chipbench: planned FP degrees {degrees}, the cell "
-                         f"states {cell.params['fp_degrees']}")
-    o = conf["optimizer"]
-    opt = adam(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"])
-    return exe, opt, degrees
 
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
@@ -187,31 +132,29 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     ``t0`` is the process's start on ``time.perf_counter``; ``kernel_mode``
     is the dispatch the run must get."""
     counter = CompileCounter()
-    kind = devices[0].device_kind
-    peak = peaks(kind, cell.pkg) if devices[0].platform == "tpu" else {}
+    kind = spec.load_kind(cell)
+    device_kind = devices[0].device_kind
+    peak = (peaks(device_kind, cell.pkg) if devices[0].platform == "tpu"
+            else {})
 
     def phase(name: str) -> None:
         log(f"set-up: {name} done at {time.perf_counter() - t0!r} s")
 
     phase("JAX start")
-    x, y = traffic.dataset(seed, cell.traffic, cell.sizes[0])
-    phase(f"dataset {x.shape}")
-    exe, opt, degrees = build(cell, devices, kernel_mode)
+    data = kind.dataset(cell, seed)
+    phase("dataset")
+    program, step = kind.build(cell, devices, kernel_mode)
     phase("plan, compile and analysis")
-    log(f"{cell.name}: {cell.sizes} batch {cell.batch} on {len(devices)} "
-        f"{kind} chip(s), kernel mode {exe.kernel_mode}, FP degrees "
-        f"{degrees}")
-    state = exe.init_state(traffic.weights_key(seed), opt)
+    log(f"{cell.name}: {len(devices)} {device_kind} chip(s)")
+    state = kind.init_state(cell, program, seed)
     jax.block_until_ready(state)
     phase("init_state")
-    step = exe.train_step(opt)
-    batcher = Batcher({"x": x, "y": y}, batch_size=cell.batch, mesh=exe.mesh)
+    feed = kind.feed(cell, program, data)
 
-    state, prog = drive_first_steps(step, state, batcher,
-                                    cell.config["optimizer"]["b1"])
-    phase(f"first {CHECK_STEPS} steps (step compile or cache load)")
+    state, prog = kind.first_steps(cell, step, state, feed)
+    phase(f"first {checks.CHECK_STEPS} steps (step compile or cache load)")
     for _ in range(int(cell.params["warmup_steps"])):
-        state, metrics = step(state, next(batcher))
+        state, metrics = step(state, next(feed))
     jax.block_until_ready(state)
     phase(f"{cell.params['warmup_steps']} warm-up steps; compilations so "
           f"far {counter.count}")
@@ -222,7 +165,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     setup_s = start - t0
     deadline = start + seconds
     while True:
-        state, metrics = step(state, next(batcher))
+        state, metrics = step(state, next(feed))
         losses.append(metrics["loss"])
         if time.perf_counter() >= deadline:
             break
@@ -231,24 +174,35 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     end = time.perf_counter()
     n = len(losses)
     window_compiles = counter.count - compiles
-    samples_per_s = n * cell.batch / (end - start)
+    samples_per_s = n * kind.samples_per_step(cell) / (end - start)
     log(f"window: {n} steps in {end - start!r} s (dispatch loop "
         f"{dispatched - start!r} s, drain {end - dispatched!r} s), "
         f"{samples_per_s!r} samples/s; compilations in the window: "
         f"{window_compiles}")
 
-    device = {"platform": devices[0].platform, "kind": kind,
+    device = {"platform": devices[0].platform, "kind": device_kind,
               "count": len(devices),
               "memory_peak_bytes": max(
                   (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                   for d in devices)}
     breakdown = None
     if trace:
-        state, ctx, breakdown = traced_window(
-            cell, step, state, batcher, degrees, peak, samples_per_s,
-            trace_dir)
-        device["busy_s"] = ctx.summary.mean("busy_ns") / 1e9
-        device["window_s"] = ctx.summary.window_ns / 1e9
+        state, steps, host, profile = traced_window(cell, step, state, feed,
+                                                    trace_dir)
+        summary = tracing.reduce(profile.trace)
+        for plane, d in summary.devices.items():
+            log(f"{plane}: idle share "
+                f"{100 * (1 - d.busy_ns / summary.window_ns)!r} %, kernel "
+                f"{d.kernel_ns / steps / 1e6!r} ms/step, xla "
+                f"{d.xla_ns / steps / 1e6!r} ms/step, collective "
+                f"{d.collective_ns / steps / 1e6!r} ms/step")
+        ctx = Context(cell=cell, flops_per_sample=kind.flops_per_sample(cell),
+                      kernel_calls=kind.kernel_calls(cell), steps=steps,
+                      host=host, summary=summary, profile=profile, peak=peak,
+                      samples_per_s=samples_per_s)
+        breakdown = summary.breakdown()
+        device["busy_s"] = summary.mean("busy_ns") / 1e9
+        device["window_s"] = summary.window_ns / 1e9
         metrics_out = {}
         for m in cell.per_layer:
             value = spec.load_reader(cell, m["name"])(ctx)
@@ -261,15 +215,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     failed = int(sum(not math.isfinite(float(v))
                      for v in jax.device_get(losses)))
-    del state, metrics, losses, batcher, step, exe
+    del state, metrics, losses, feed, step, program
 
     t = time.perf_counter()
-    reference = spec.load_reference(cell)
-    ref = reference.train(
-        traffic.weights_key(seed), cell.sizes,
-        traffic.step_rows(x, y, cell.batch, CHECK_STEPS),
-        cell.config["optimizer"], device=devices[0])
-    log(f"reference: {CHECK_STEPS} steps in {time.perf_counter() - t!r} s")
+    ref = kind.reference(cell, seed, data, devices[0])
+    log(f"reference: {checks.CHECK_STEPS} steps in "
+        f"{time.perf_counter() - t!r} s")
     log(f"program losses {list(prog.losses)}, reference {list(ref.losses)}")
     numbers = checks.compare(prog, ref)
     log(f"compared numbers {numbers}")
@@ -284,10 +235,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     return result
 
 
-def traced_window(cell, step, state, batcher, degrees, peak, samples_per_s,
-                  trace_dir):
+def traced_window(cell, step, state, feed, trace_dir):
     """Trace the cell's fixed number of steps with the host spans; return
-    the state, the readers' context and the breakdown."""
+    the state, the step count, the host seconds per step by span and the
+    window's profile, loaded before its directory is removed."""
     steps = int(cell.params["trace_steps"])
     host: dict[str, list[float]] = {"batch": [], "dispatch": []}
     own_dir = trace_dir is None
@@ -300,7 +251,7 @@ def traced_window(cell, step, state, batcher, degrees, peak, samples_per_s,
             for _ in range(steps):
                 a = time.perf_counter()
                 with jax.profiler.TraceAnnotation("batch"):
-                    batch = next(batcher)
+                    batch = next(feed)
                 b = time.perf_counter()
                 with jax.profiler.TraceAnnotation("dispatch"):
                     state, metrics = step(state, batch)
@@ -309,20 +260,11 @@ def traced_window(cell, step, state, batcher, degrees, peak, samples_per_s,
                 host["dispatch"].append(c - b)
             with jax.profiler.TraceAnnotation("wait"):
                 jax.block_until_ready((state, metrics))
-        path = next(out.glob("**/*.xplane.pb"))
-        summary = tracing.reduce(tracing.load_xplane(path))
+        profile = scopes.load(next(out.glob("**/*.xplane.pb")))
     finally:
         if own_dir:
             shutil.rmtree(out, ignore_errors=True)
-    for plane, d in summary.devices.items():
-        log(f"{plane}: idle share "
-            f"{100 * (1 - d.busy_ns / summary.window_ns)!r} %, kernel "
-            f"{d.kernel_ns / steps / 1e6!r} ms/step, xla "
-            f"{d.xla_ns / steps / 1e6!r} ms/step, collective "
-            f"{d.collective_ns / steps / 1e6!r} ms/step")
-    ctx = Context(cell=cell, degrees=degrees, steps=steps, host=host,
-                  summary=summary, peak=peak, samples_per_s=samples_per_s)
-    return state, ctx, summary.breakdown()
+    return state, steps, host, profile
 
 
 def main(argv: list[str], t0: float) -> int:

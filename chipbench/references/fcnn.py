@@ -26,12 +26,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.checks import Readings, leaf_names
+from chipbench.checks import Readings
 
 FAULTS = ("half_batch", "altered_answer")
 # A garbage value where the output layer produces the first row's label
 # logit: it stands 10 below what the layer computed.
 ALTERATION = 10.0
+
+
+def leaf_names(n_layers: int) -> list[str]:
+    """``w1, b1, w2, ...``: the names both sides give the leaves."""
+    return [f"{kind}{i}" for i in range(1, n_layers + 1) for kind in "wb"]
 
 
 def init(key, sizes: Sequence[int]) -> list[tuple[jax.Array, jax.Array]]:

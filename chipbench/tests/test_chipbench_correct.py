@@ -15,7 +15,7 @@ from pathlib import Path
 import jax
 import pytest
 
-from chipbench import calibrate, checks, harness, spec, traffic
+from chipbench import calibrate, checks, harness, spec
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2**33 + 12345        # wider than 32 bits, as the driver's may be
@@ -55,13 +55,20 @@ def _run(cell):
 def test_control_is_not_correct(cell):
     """The reference in bfloat16 in the program's place fails a limit;
     the float32 reference against itself passes."""
-    ref_mod = spec.load_reference(cell)
-    x, y = traffic.dataset(SEED, cell.traffic, cell.sizes[0])
-    rows = traffic.step_rows(x, y, cell.batch, harness.CHECK_STEPS)
-    key = traffic.weights_key(SEED)
-    opt = cell.config["optimizer"]
-    ref = ref_mod.train(key, cell.sizes, rows, opt)
-    control = ref_mod.train(key, cell.sizes, rows, opt, dtype="bfloat16")
+    _control_fails(cell)
+
+
+def test_nn6_b128_control_is_not_correct():
+    """As above, at NN6 width and batch 128, against that cell's limits."""
+    _control_fails(spec.load_cell("nn6.b128"))
+
+
+def _control_fails(cell):
+    kind = spec.load_kind(cell)
+    data = kind.dataset(cell, SEED)
+    device = jax.devices()[0]
+    ref = kind.reference(cell, SEED, data, device)
+    control = kind.reference(cell, SEED, data, device, planted="control")
     limits = cell.params["limits"]
     assert checks.judge(checks.compare(ref, ref), limits)[0]
     ok, table = checks.judge(checks.compare(control, ref), limits)

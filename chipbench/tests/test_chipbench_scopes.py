@@ -7,6 +7,7 @@ from pathlib import Path
 
 import pytest
 
+from chipbench import harness, spec
 from chipbench import scopes as S
 from chipbench import trace as T
 
@@ -178,3 +179,17 @@ def test_scoped_recorded_trace_leaves_only_xla_copies_unscoped(scoped):
     assert {name for name, _ in unscoped} <= {"copy", "copy-start",
                                               "copy-done"}
     assert not any(scope.startswith("jit(step)/") for _, scope in unscoped)
+
+
+@pytest.mark.parametrize("metric", ["optimizer_ms", "pad_ms", "unscoped_ms",
+                                    "batch_put_us", "idle_put_pct"])
+def test_scope_readers_on_the_recorded_trace(scoped, metric):
+    """Each per-layer reader of the program's scopes and spans gives what
+    ``split`` reads from the same profile."""
+    cell = spec.load_cell("nn1.b128")
+    ctx = harness.Context(
+        cell=cell, flops_per_sample=0.0, kernel_calls=[], steps=10, host={},
+        summary=T.reduce(scoped.trace), profile=scoped, peak={},
+        samples_per_s=0.0)
+    got = spec.load_reader(cell, metric)(ctx)
+    assert got is not None and got == S.split(scoped, steps=10)[metric]

@@ -35,7 +35,8 @@ def added(tmp_path):
     }
     _write(tmp_path / "BENCHMARK.json", bench)
     pkg = tmp_path / "chipbench"
-    _write(pkg / "configs" / "tiny.json", {"layer_sizes": [4, 8, 2],
+    _write(pkg / "configs" / "tiny.json", {"kind": "fcnn",
+                                           "layer_sizes": [4, 8, 2],
                                            "reference": "fcnn"})
     _write(pkg / "traffic" / "t8.json", {"batch": 8, "samples": 64})
     for cell in ("tiny.b8", "tiny.b8.x4"):
@@ -47,7 +48,8 @@ def added(tmp_path):
 
 def test_cell_added_as_files(added):
     cell = spec.load_cell("tiny.b8", root=added)
-    assert (cell.chips, cell.sizes, cell.batch) == (1, [4, 8, 2], 8)
+    assert (cell.chips, cell.config["layer_sizes"], cell.traffic["batch"]) \
+        == (1, [4, 8, 2], 8)
     assert cell.params == {"trace_steps": 3}
     assert [m["name"] for m in cell.per_layer] == ["answer", "everywhere"]
     assert spec.load_reader(cell, "answer")(21.0) == 42.0
@@ -62,6 +64,15 @@ def test_metric_listed_for_some_cells_only(added):
 def test_unknown_cell(added):
     with pytest.raises(KeyError, match="no workload"):
         spec.load_cell("tiny.b9", root=added)
+
+
+def test_configuration_without_kind_names_its_file(added):
+    path = added / "chipbench" / "configs" / "tiny.json"
+    conf = json.loads(path.read_text())
+    del conf["kind"]
+    _write(path, conf)
+    with pytest.raises(ValueError, match=r"configs/tiny\.json.*kind"):
+        spec.load_cell("tiny.b8", root=added)
 
 
 def _bench():
@@ -92,11 +103,19 @@ def test_benchmark_json_names_and_files():
     assert fours <= max(1, len(bench["workloads"]) // 2)
 
 
+KIND_INTERFACE = ("dataset", "build", "init_state", "feed", "first_steps",
+                  "reference", "samples_per_step", "flops_per_sample",
+                  "kernel_calls")
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in _bench()["workloads"]])
 def test_every_cell_loads(cell):
     c = spec.load_cell(cell)
     assert c.chips in (1, 4)
-    assert len(c.params["fp_degrees"]) == len(c.sizes) - 1
+    kind = spec.load_kind(c)
+    assert all(callable(getattr(kind, f)) for f in KIND_INTERFACE)
+    if c.config["kind"] == "fcnn":
+        assert len(c.params["fp_degrees"]) == len(kind.sizes(c)) - 1
     assert c.params["limits"] and set(c.params["limits"]) <= set(
         checks.NAMES)
     assert (c.pkg / "references" / f"{c.config['reference']}.py").is_file()

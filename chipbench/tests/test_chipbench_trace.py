@@ -7,8 +7,8 @@ from pathlib import Path
 
 import pytest
 
+from chipbench import spec
 from chipbench import trace as T
-from chipbench import work
 
 DATA = Path(__file__).resolve().parents[1] / "testdata"
 
@@ -106,7 +106,8 @@ def test_recorded_trace_kernels(nn1_trace):
     input layer's dgrad is never run."""
     ops = nn1_trace.devices["/device:TPU:0"]
     kernels = [o.name for o in ops if o.category == "kernel"]
-    calls = work.step_calls([784, 1000, 500, 10], 128)
+    cell = spec.load_cell("nn1.b128")
+    calls = spec.load_kind(cell).kernel_calls(cell)
     assert len(kernels) == 10 * len(calls)
     assert set(kernels) == {c.kernel for c in calls}
     assert not [o for o in ops if o.category == "collective"]

@@ -1,8 +1,11 @@
-"""Work counters against figures worked out by hand."""
+"""Work counters against figures worked out by hand, and the readers that
+use them."""
+
+from pathlib import Path
 
 import pytest
 
-from chipbench import work
+from chipbench import harness, spec, trace, work
 
 NN1 = [784, 1000, 500, 10]
 NN6 = [1024, 4000, 1000, 4000, 1000, 4000, 1000, 4000, 10]
@@ -52,3 +55,40 @@ def test_least_time_takes_the_larger_bound():
     calls = [work.Call("a", flops=2e12, bytes=1e9),    # 10 ms vs 1 ms
              work.Call("b", flops=1e9, bytes=4e9)]     # 5 us vs 4 ms
     assert work.least_time_s(calls, 2e14, 1e12) == pytest.approx(0.014)
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """The reduction of ten NN1 batch-128 steps traced on one TPU v5e, and
+    its profile."""
+    from chipbench import scopes
+    profile = scopes.load(Path(__file__).resolve().parents[1] / "testdata"
+                          / "nn1.b128.10steps.scoped.xplane.pb.gz")
+    return trace.reduce(profile.trace), profile
+
+
+@pytest.mark.parametrize("name, sizes, batch, degrees", [
+    ("nn1.b128", NN1, 128, [1, 1, 1]),
+    ("nn6.b1024", NN6, 1024, [1] * 8),
+    ("nn6.b128", NN6, 128, [1] * 8)])
+def test_mfu_and_roofline_from_the_kinds_counts(recorded, name, sizes,
+                                                batch, degrees):
+    """``mfu`` and ``kernel_roofline`` read the kind's counts from the
+    context and give what the FCNN work counters give for the cell."""
+    summary, profile = recorded
+    cell = spec.load_cell(name)
+    kind = spec.load_kind(cell)
+    peak = harness.peaks("TPU v5 lite", cell.pkg)
+    ctx = harness.Context(
+        cell=cell, flops_per_sample=kind.flops_per_sample(cell),
+        kernel_calls=kind.kernel_calls(cell), steps=10, host={},
+        summary=summary, profile=profile, peak=peak, samples_per_s=1e5)
+    flops = work.model_flops_per_sample(sizes) * 1e5
+    assert spec.load_reader(cell, "mfu")(ctx) == (
+        100.0 * flops / (1 * peak["bf16_flops_per_s"]))
+    kernel_s = summary.mean("kernel_ns") * 1 / 1e9
+    least = 10 * work.least_time_s(work.step_calls(sizes, batch, degrees),
+                                   peak["bf16_flops_per_s"],
+                                   peak["hbm_bytes_per_s"])
+    assert spec.load_reader(cell, "kernel_roofline")(ctx) == (
+        100.0 * least / kernel_s)
