@@ -13,6 +13,7 @@ from repro.configs.base import (  # noqa: F401
 
 # one import per assigned architecture — registration is a side effect
 from repro.configs import (  # noqa: F401
+    deepseek_v2_lite,
     granite_3_2b,
     granite_moe_1b,
     mamba2_2_7b,

@@ -18,7 +18,7 @@ __all__ = ["ModelConfig", "ShapeSpec", "SHAPES", "register", "get_config",
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                    # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                    # dense | moe | mla_moe | ssm | hybrid | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -37,8 +37,26 @@ class ModelConfig:
     moe_d_ff: int = 0              # per-expert hidden dim
     n_shared_experts: int = 0
     router_aux_coef: float = 0.01
-    capacity_factor: float = 1.25
-    moe_group_size: int = 512      # tokens per dispatch group
+    norm_topk_prob: bool = True    # renormalise the top-k router weights
+    # the experts this device holds, [lo, hi) of n_experts; () holds all.
+    # The router still routes over all n_experts; pairs bound for an
+    # absent expert contribute nothing here (expert parallelism's share).
+    expert_range: tuple[int, ...] = ()
+    first_k_dense: int = 0         # leading dense layers (SwiGLU of d_ff)
+    # --- latent attention (MLA, DeepSeek-V2) ---
+    kv_lora_rank: int = 0          # 0 -> no MLA
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- YaRN RoPE scaling (factor 0 -> plain RoPE) ---
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # > 0: every weight matrix ~ N(0, init_std^2), drawn per leaf by name
+    init_std: float = 0.0
     # --- SSM (Mamba2/SSD) ---
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -91,6 +109,11 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """[lo, hi) of the experts this device holds."""
+        return tuple(self.expert_range) or (0, self.n_experts)
 
     @property
     def d_inner(self) -> int:

@@ -18,7 +18,7 @@ def smoke() -> ModelConfig:
     return full().replace(
         name="granite-moe-1b-a400m-smoke", n_layers=2, d_model=64, n_heads=4,
         n_kv_heads=2, vocab_size=256, head_dim=16,
-        n_experts=8, experts_per_token=2, moe_d_ff=32, moe_group_size=32,
+        n_experts=8, experts_per_token=2, moe_d_ff=32,
         dtype="float32", param_dtype="float32", remat=False,
     )
 

@@ -20,7 +20,6 @@ def smoke() -> ModelConfig:
         name="qwen2-moe-a2.7b-smoke", n_layers=2, d_model=64, n_heads=4,
         n_kv_heads=4, vocab_size=256, head_dim=16,
         n_experts=6, experts_per_token=2, moe_d_ff=32, n_shared_experts=2,
-        moe_group_size=32,
         dtype="float32", param_dtype="float32", remat=False,
     )
 

@@ -160,7 +160,7 @@ _TRAIN_PURE_FSDP = (
      "cache_batch": ("pod", "data", "model"),
      "activation_heads": None, "activation_kv_heads": None,
      "activation_mlp": None, "activation_vocab": None,
-     "activation_exp": None, "kv_heads": None, "table_embed": None},
+     "kv_heads": None, "table_embed": None},
     {"attn_chunk_threshold": 2048 * 2048, "fused_ce": True,
      "embed_onehot": True},
 )

@@ -93,21 +93,21 @@ VARIANTS: dict[str, tuple[dict, dict, dict]] = {
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None},
+         "kv_heads": None},
         {}, {}),
     "pure_fsdp+vp": (
         {"activation_batch": ("pod", "data", "model"),
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {}, {}),
     "pure_fsdp+vp+bf16comm": (
         {"activation_batch": ("pod", "data", "model"),
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"accum_dtype": "bfloat16"}, {}),
     # pure FSDP but logits stay vocab-sharded + chunked attention at 4k
     "pure_fsdp+vTP+chunk": (
@@ -115,14 +115,14 @@ VARIANTS: dict[str, tuple[dict, dict, dict]] = {
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048}, {}),
     "pure_fsdp+vTP+chunk+bf16comm": (
         {"activation_batch": ("pod", "data", "model"),
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048, "accum_dtype": "bfloat16"},
         {}),
     "pure_fsdp+fce+chunk": (
@@ -130,14 +130,14 @@ VARIANTS: dict[str, tuple[dict, dict, dict]] = {
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048, "fused_ce": True}, {}),
     "pure_fsdp+fce+chunk+bf16comm": (
         {"activation_batch": ("pod", "data", "model"),
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048, "fused_ce": True,
          "accum_dtype": "bfloat16"}, {}),
     "pure_fsdp+fce+oh+chunk": (
@@ -145,7 +145,7 @@ VARIANTS: dict[str, tuple[dict, dict, dict]] = {
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None, "activation_vocab": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048, "fused_ce": True,
          "embed_onehot": True}, {}),
     # serving layout: weights 2D-TP (mlp over model*data), nothing gathered
@@ -169,7 +169,7 @@ VARIANTS: dict[str, tuple[dict, dict, dict]] = {
          "cache_batch": ("pod", "data", "model"),
          "activation_heads": None, "activation_kv_heads": None,
          "activation_mlp": None,
-         "activation_exp": None, "kv_heads": None, "table_embed": None},
+         "kv_heads": None, "table_embed": None},
         {"attn_chunk_threshold": 2048 * 2048}, {"microbatches": 2}),
 }
 

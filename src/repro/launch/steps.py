@@ -4,7 +4,9 @@
 in/out shardings derived from the model's logical axes (shape-aware: axes
 that don't divide are demoted — see parallel.sharding.resolve_spec),
 donated state, and optional int8 gradient compression (error-feedback
-residual rides in the state).
+residual rides in the state).  The metrics are the loss, the gradient's
+global norm and the model's own step metrics; the clip and the norm run
+under the ``grad_norm`` scope, AdamW under ``optimizer`` (``repro.obs``).
 
 ``build_prefill_step`` / ``build_decode_step``: the serving pair; decode
 donates the KV cache (in-place update at scale).
@@ -19,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ShapeSpec
 from repro.models.api import Model
 from repro.optim import adamw, clip_by_global_norm
@@ -36,6 +39,7 @@ Params = dict[str, Any]
 class TrainSettings:
     learning_rate: float = 3e-4
     weight_decay: float = 0.01
+    b2: float = 0.999                     # AdamW's second-moment decay
     grad_clip: float = 1.0
     grad_compression: str = "none"        # "none" | "int8"
     microbatches: int = 1
@@ -54,9 +58,15 @@ def state_axes(model: Model, settings: TrainSettings) -> Params:
     return st
 
 
+def optimizer(settings: TrainSettings):
+    """AdamW as ``settings`` states it."""
+    return adamw(settings.learning_rate, b2=settings.b2,
+                 weight_decay=settings.weight_decay)
+
+
 def init_train_state(model: Model, settings: TrainSettings, key) -> Params:
     params = model.init(key)
-    opt = adamw(settings.learning_rate, weight_decay=settings.weight_decay)
+    opt = optimizer(settings)
     st = {
         "params": params,
         "opt": opt.init(params),
@@ -80,7 +90,7 @@ def build_train_step(
     rules: AxisRules = DEFAULT_RULES,
 ):
     """Returns (jitted step, state_shardings, batch_shardings, state_spec)."""
-    opt = adamw(settings.learning_rate, weight_decay=settings.weight_decay)
+    opt = optimizer(settings)
 
     state_spec = train_state_spec(model, settings)
     st_shardings = shape_aware_shardings(
@@ -99,28 +109,29 @@ def build_train_step(
             loss, grads = gradsync.accumulate_grads(
                 model.loss_fn, state["params"], mb,
                 unroll=scan_unroll_of(model.cfg))
+            metrics = {}
         else:
-            loss, grads = jax.value_and_grad(model.loss_fn)(
-                state["params"], batch)
-        grads, gnorm = clip_by_global_norm(grads, settings.grad_clip)
+            (loss, metrics), grads = jax.value_and_grad(
+                model.loss_and_metrics, has_aux=True)(state["params"], batch)
+        with jax.named_scope(obs.GRAD_NORM):
+            grads, gnorm = clip_by_global_norm(grads, settings.grad_clip)
         new_state = dict(state)
         if settings.grad_compression == "int8":
             grads, new_res = gradsync.compress_grads_ef(
                 grads, state["residual"])
             new_state["residual"] = new_res
-        params, opt_state = opt.update(grads, state["opt"], state["params"],
-                                       state["step"])
-        new_state["params"] = params
-        new_state["opt"] = opt_state
-        new_state["step"] = state["step"] + 1
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        with jax.named_scope(obs.OPTIMIZER):
+            params, opt_state = opt.update(grads, state["opt"],
+                                           state["params"], state["step"])
+            new_state["params"] = params
+            new_state["opt"] = opt_state
+            new_state["step"] = state["step"] + 1
+        return new_state, {"loss": loss, "grad_norm": gnorm, **metrics}
 
-    metrics_sh = {"loss": NamedSharding(mesh, P()),
-                  "grad_norm": NamedSharding(mesh, P())}
     jitted = jax.jit(
         step,
         in_shardings=(st_shardings, batch_shardings),
-        out_shardings=(st_shardings, metrics_sh),
+        out_shardings=(st_shardings, NamedSharding(mesh, P())),
         donate_argnums=(0,),
     )
     return jitted, st_shardings, batch_shardings, state_spec

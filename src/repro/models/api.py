@@ -2,7 +2,11 @@
 functions close over nothing — params/batches are explicit pytrees, so
 every function jits and shards cleanly.
 
-Families: dense | moe | ssm | hybrid | encdec | vlm.
+Families: dense | moe | mla_moe | ssm | hybrid | encdec | vlm.
+
+``loss_and_metrics(params, batch) -> (loss, metrics)`` is the loss with
+the model's own step metrics (a dict of scalars; empty for most
+families); ``loss_fn`` is its loss alone.
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ class Model:
     param_axes: Callable[[], Params]
     forward: Callable[..., jax.Array]
     loss_fn: Callable[..., jax.Array]
+    loss_and_metrics: Callable[..., tuple[jax.Array, Params]]
     init_cache: Callable[..., Params]
     cache_axes: Callable[[], Params]
     prefill: Callable[..., tuple]
@@ -122,6 +127,9 @@ def get_model(cfg: ModelConfig) -> Model:
     elif fam == "moe":
         from repro.models import moe as mod
         specs, baxes = _lm_input_specs, _lm_batch_axes
+    elif fam == "mla_moe":
+        from repro.models import deepseek_v2 as mod
+        specs, baxes = _lm_input_specs, _lm_batch_axes
     elif fam == "ssm":
         from repro.models import mamba2 as mod
         specs, baxes = _lm_input_specs, _lm_batch_axes
@@ -143,12 +151,20 @@ def get_model(cfg: ModelConfig) -> Model:
                                   kw.get("enc_len", max_len))
         return mod.init_cache(cfg, batch, max_len)
 
+    if hasattr(mod, "loss_and_metrics"):
+        def loss_and_metrics(p, b):
+            return mod.loss_and_metrics(p, b, cfg)
+    else:
+        def loss_and_metrics(p, b):
+            return mod.loss_fn(p, b, cfg), {}
+
     return Model(
         cfg=cfg,
         init=lambda key: mod.init(key, cfg),
         param_axes=lambda: mod.param_axes(cfg),
         forward=lambda p, b: mod.forward(p, b, cfg),
         loss_fn=lambda p, b: mod.loss_fn(p, b, cfg),
+        loss_and_metrics=loss_and_metrics,
         init_cache=init_cache,
         cache_axes=lambda: mod.cache_axes(cfg),
         prefill=lambda p, b, max_len: mod.prefill(p, b, cfg, max_len),

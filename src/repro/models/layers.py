@@ -251,20 +251,21 @@ def _project_qkv(p: Params, x: jax.Array, positions, theta,
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
-    """q: (B,Lq,H,D); k,v: (B,Lk,KV,D); GQA via head grouping."""
+def _sdpa(q, k, v, mask, scale: float | None = None):
+    """q: (B,Lq,H,D); k: (B,Lk,KV,D); v: (B,Lk,KV,Dv); GQA via head
+    grouping; scores scaled by ``scale``, 1/sqrt(D) where None."""
     b, lq, h, d = q.shape
     kv = k.shape[2]
     g = h // kv
     qg = q.reshape(b, lq, kv, g, d)
     logits = jnp.einsum("blkgd,bmkd->bkglm", qg, k,
                         preferred_element_type=jnp.float32)
-    logits = logits / math.sqrt(d)
+    logits = logits / math.sqrt(d) if scale is None else logits * scale
     logits = jnp.where(mask, logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkglm,bmkd->blkgd", w.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, lq, h, d).astype(q.dtype)
+    return out.reshape(b, lq, h, v.shape[-1]).astype(q.dtype)
 
 
 # Above this many score elements per head-group, attention switches to the
@@ -291,12 +292,18 @@ def remat_wrap(cfg, body):
     return jax.checkpoint(body, policy=policy)
 
 
-def _flash_fwd_core(qg, kc, vc, chunk, unroll):
-    """qg: (B,Lq,KV,G,D); kc/vc: (B,NC,chunk,KV,D) -> out grouped + lse."""
+def _softmax_scale(d: int, scale: float | None) -> float:
+    return 1.0 / math.sqrt(d) if scale is None else scale
+
+
+def _flash_fwd_core(qg, kc, vc, chunk, unroll, scale=None):
+    """qg: (B,Lq,KV,G,D); kc: (B,NC,chunk,KV,D); vc: (B,NC,chunk,KV,Dv)
+    -> out grouped (B,KV,G,Lq,Dv) + lse."""
     b, lq = qg.shape[0], qg.shape[1]
     kv, g, d = qg.shape[2], qg.shape[3], qg.shape[4]
+    dv = vc.shape[-1]
     nc = kc.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    scale = _softmax_scale(d, scale)
     rows = jnp.arange(lq)
 
     def body(carry, xs):
@@ -318,7 +325,7 @@ def _flash_fwd_core(qg, kc, vc, chunk, unroll):
 
     m0 = jnp.full((b, kv, g, lq), -1e30, jnp.float32)
     l0 = jnp.zeros((b, kv, g, lq), jnp.float32)
-    a0 = jnp.zeros((b, kv, g, lq, d), jnp.float32)
+    a0 = jnp.zeros((b, kv, g, lq, dv), jnp.float32)
     (m_f, l_f, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
         (jnp.arange(nc), jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0)),
@@ -328,56 +335,53 @@ def _flash_fwd_core(qg, kc, vc, chunk, unroll):
     return out_g, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _sdpa_chunked_causal(q, k, v, chunk: int = _SDPA_CHUNK,
-                         unroll: bool | int = 1):
+                         unroll: bool | int = 1, scale: float | None = None):
     """Causal flash attention, kv-chunked online softmax with a flash-style
     custom VJP: the backward recomputes per-chunk probabilities from the
     saved log-sum-exp instead of letting scan-AD stack O(Lq·Lk) residuals
-    (which would erase the memory win — measured 3×2.7 GB per layer at 4k
-    before this VJP existed; see EXPERIMENTS.md §Perf).
+    (which would erase the memory win: 3×2.7 GB per layer at 4k before
+    this VJP existed).
 
-    q: (B,Lq,H,D); k,v: (B,Lk,KV,D); Lq == Lk (self-attention prefill).
+    q: (B,Lq,H,D); k: (B,Lk,KV,D); v: (B,Lk,KV,Dv), where Dv may differ
+    from D (latent attention); Lq == Lk (self-attention prefill).  The
+    scores are scaled by ``scale``, 1/sqrt(D) where None.
     """
+    out, _ = _sdpa_chunked_fwd(q, k, v, chunk, unroll, scale)
+    return out
+
+
+def _sdpa_chunked_fwd(q, k, v, chunk, unroll, scale=None):
     b, lq, h, d = q.shape
     lk, kv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     g = h // kv
     nc = lk // chunk
     qg = q.reshape(b, lq, kv, g, d)
     kc = k.reshape(b, nc, chunk, kv, d)
-    vc = v.reshape(b, nc, chunk, kv, d)
-    out_g, _ = _flash_fwd_core(qg, kc, vc, chunk, unroll)
-    out = jnp.moveaxis(out_g, 3, 1)                       # (B,KV,G,Lq,D)->(B,Lq,KV,G,D)
-    return out.reshape(b, lq, h, d).astype(q.dtype)
-
-
-def _sdpa_chunked_fwd(q, k, v, chunk, unroll):
-    b, lq, h, d = q.shape
-    lk, kv = k.shape[1], k.shape[2]
-    g = h // kv
-    nc = lk // chunk
-    qg = q.reshape(b, lq, kv, g, d)
-    kc = k.reshape(b, nc, chunk, kv, d)
-    vc = v.reshape(b, nc, chunk, kv, d)
-    out_g, lse = _flash_fwd_core(qg, kc, vc, chunk, unroll)
-    out = jnp.moveaxis(out_g, 3, 1).reshape(b, lq, h, d).astype(q.dtype)
+    vc = v.reshape(b, nc, chunk, kv, dv)
+    out_g, lse = _flash_fwd_core(qg, kc, vc, chunk, unroll, scale)
+    # (B,KV,G,Lq,Dv) -> (B,Lq,KV,G,Dv)
+    out = jnp.moveaxis(out_g, 3, 1).reshape(b, lq, h, dv).astype(q.dtype)
     return out, (q, k, v, out, lse)
 
 
-def _sdpa_chunked_bwd(chunk, unroll, res, dout):
+def _sdpa_chunked_bwd(chunk, unroll, scale, res, dout):
     q, k, v, out, lse = res
     b, lq, h, d = q.shape
     lk, kv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     g = h // kv
     nc = lk // chunk
-    scale = 1.0 / math.sqrt(d)
+    scale = _softmax_scale(d, scale)
     qg = q.reshape(b, lq, kv, g, d).astype(jnp.float32)
-    og = jnp.moveaxis(dout.reshape(b, lq, kv, g, d), 1, 3).astype(jnp.float32)
-    outg = jnp.moveaxis(out.reshape(b, lq, kv, g, d), 1, 3).astype(jnp.float32)
+    og = jnp.moveaxis(dout.reshape(b, lq, kv, g, dv), 1, 3).astype(jnp.float32)
+    outg = jnp.moveaxis(out.reshape(b, lq, kv, g, dv), 1, 3).astype(jnp.float32)
     # delta[r] = sum_d out[r,d] * dout[r,d]  (flash-bwd row correction)
     delta = jnp.sum(outg * og, axis=-1)                   # (B,KV,G,Lq)
     kc = k.reshape(b, nc, chunk, kv, d)
-    vc = v.reshape(b, nc, chunk, kv, d)
+    vc = v.reshape(b, nc, chunk, kv, dv)
     rows = jnp.arange(lq)
 
     def body(dq_acc, xs):
@@ -406,8 +410,8 @@ def _sdpa_chunked_bwd(chunk, unroll, res, dout):
         (jnp.arange(nc), jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0)),
         unroll=unroll)
     dk = jnp.moveaxis(dk_c, 0, 1).reshape(b, lk, kv, d).astype(k.dtype)
-    dv = jnp.moveaxis(dv_c, 0, 1).reshape(b, lk, kv, d).astype(v.dtype)
-    return dq.reshape(b, lq, h, d).astype(q.dtype), dk, dv
+    dval = jnp.moveaxis(dv_c, 0, 1).reshape(b, lk, kv, dv).astype(v.dtype)
+    return dq.reshape(b, lq, h, d).astype(q.dtype), dk, dval
 
 
 _sdpa_chunked_causal.defvjp(_sdpa_chunked_fwd, _sdpa_chunked_bwd)

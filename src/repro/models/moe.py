@@ -1,16 +1,31 @@
 """Token-choice top-k Mixture-of-Experts transformer (granite-3.0-moe,
-qwen2-moe with shared experts).
+qwen2-moe with shared experts), and the expert layer that DeepSeek-V2
+(``models/deepseek_v2.py``) shares.
 
-Dispatch uses the grouped one-hot einsum formulation (Mesh-TF / MaxText
-style): tokens are split into groups of ``moe_group_size``; within a group
-each expert accepts at most C = ceil(group · k / E · capacity_factor)
-tokens (overflow dropped, standard for capacity-based MoE).  The dispatch
-einsum contracts a (G, T, E, C) one-hot against (G, T, d) activations and,
-with tokens sharded on "data" and experts on "model" (EP), XLA lowers the
-boundary into the canonical MoE all-to-all pair.
+The expert layer is dropless and is told which experts it holds
+(``cfg.held_experts``, [lo, hi) of ``n_experts``): the router scores
+every expert and picks each token's top k, and the layer computes only
+its own experts' part of the result.  It sorts the (token, slot) pairs by
+held expert (pairs bound for an absent expert sort last), gathers their
+rows and runs each expert's SwiGLU as ``jax.lax.ragged_dot`` grouped
+matmuls over the sorted rows, then adds weight × output back to each
+token.  No expert has a capacity, so no token is dropped: the grouped
+matmuls take all tokens × k sorted pairs, the held ones first, and the
+group sizes end where the held pairs do.  An absent expert's part is left
+out, as expert parallelism leaves it to the chip that holds it; a model
+holding every expert computes the whole layer.
 
-The router aux (load-balance) loss is threaded through the layer-scan carry
-— no out-of-band state, no leaked tracers.
+Expert parallelism: under a mesh whose rules split the ``experts`` axis
+(``"model"`` by default), the routed part runs under ``shard_map``.  Each
+device holds its slice of the held experts and sorts and computes the
+pairs bound for them among its batch rows' tokens (whole over the expert
+axes, split over the others), and a sum over the expert axes adds the
+devices' parts — the parts of disjoint held ranges add up to the whole
+layer.
+
+The router's sequence-level load-balance loss (DeepSeek-V2's, over all
+experts) is threaded through the layer-scan carry — no out-of-band state,
+no leaked tracers.
 
 ONoC-planner note (DESIGN.md §Arch-applicability): experts map onto the
 paper's "neurons evenly mapped to m_i cores" with the all-to-all replacing
@@ -20,16 +35,18 @@ the ring broadcast; g() gains an all-to-all term in core/planner.py.
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import transformer as T
-from repro.parallel.sharding import shard_constraint
+from repro.parallel.sharding import shard_constraint, split_axes
 
 Params = dict[str, Any]
 
@@ -37,10 +54,12 @@ Params = dict[str, Any]
 def init_moe_mlp(key, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 5)
     dtype = jnp.dtype(cfg.param_dtype)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
+    lo, hi = cfg.held_experts
+    e, d, f = hi - lo, cfg.d_model, cfg.moe_d_ff
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p: Params = {
-        "router": (jax.random.normal(ks[0], (d, e)) * s_in).astype(jnp.float32),
+        "router": (jax.random.normal(ks[0], (d, cfg.n_experts))
+                   * s_in).astype(jnp.float32),
         "w_gate": (jax.random.normal(ks[1], (e, d, f)) * s_in).astype(dtype),
         "w_up": (jax.random.normal(ks[2], (e, d, f)) * s_in).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (e, f, d)) * s_out).astype(dtype),
@@ -63,66 +82,168 @@ def moe_mlp_axes(cfg: ModelConfig) -> Params:
     return p
 
 
-def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig):
-    """x: (B, S, d) -> (y, aux_loss)."""
-    b, s, d = x.shape
+class Routing(NamedTuple):
+    weights: jax.Array     # (N, k) f32, the combine weight of each slot
+    experts: jax.Array     # (N, k) int32, the expert of each slot
+    scores: jax.Array      # (N, E) f32, softmax over every expert
+
+
+def route(router: jax.Array, x: jax.Array, cfg: ModelConfig) -> Routing:
+    """Softmax scores over all experts in f32, greedy top-k; the weights
+    are renormalised over the k where ``cfg.norm_topk_prob``.  x: (N, d)."""
+    logits = jnp.dot(x.astype(jnp.float32), router,
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(scores, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return Routing(weights, experts, scores)
+
+
+def sequence_aux_loss(r: Routing, batch: int, cfg: ModelConfig) -> jax.Array:
+    """DeepSeek-V2's sequence-level balance loss over all experts, before
+    its coefficient: mean over sequences of Σ_i f_i P_i, with f_i =
+    E / (k·S) · (slots of the sequence routed to i) and P_i the mean score
+    of i over the sequence."""
     e, k = cfg.n_experts, cfg.experts_per_token
-    tokens = x.reshape(b * s, d)
-    n = tokens.shape[0]
-    g_size = min(cfg.moe_group_size, n)
-    n_groups = -(-n // g_size)                                  # ceil
-    padded = n_groups * g_size
-    valid = (jnp.arange(padded) < n).astype(jnp.float32)
-    if padded > n:
-        tokens = jnp.pad(tokens, ((0, padded - n), (0, 0)))
-    tokens = tokens.reshape(n_groups, g_size, d)
-    valid = valid.reshape(n_groups, g_size)                     # (G,T)
+    scores = r.scores.reshape(batch, -1, e)
+    s = scores.shape[1]
+    counts = jnp.sum(jax.nn.one_hot(r.experts.reshape(batch, s * k), e,
+                                    dtype=jnp.float32), axis=1)
+    f = counts * (e / (k * s))
+    return jnp.mean(jnp.sum(f * jnp.mean(scores, axis=1), axis=-1))
 
-    logits = jnp.einsum("gtd,de->gte", tokens.astype(jnp.float32),
-                        p["router"])                            # (G,T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)             # (G,T,k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
 
-    onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)   # (G,T,k,E)
-    gates_full = jnp.einsum("gtk,gtke->gte", gate_vals, onehot)
-    sel = jnp.sum(onehot, axis=2)                               # (G,T,E) 0/1
-    # padding tokens route nowhere and consume no expert capacity
-    sel = sel * valid[..., None]
-    gates_full = gates_full * valid[..., None]
+class Dispatch(NamedTuple):
+    order: jax.Array        # (N·k,) pair indices, held experts' first
+    tokens: jax.Array       # (N·k,) the token of each sorted pair
+    weights: jax.Array      # (N·k,) its combine weight, 0 where not held
+    group_sizes: jax.Array  # (held,) pairs per held expert, in order
 
-    cap = max(1, int(math.ceil(g_size * k / e * cfg.capacity_factor)))
-    pos = (jnp.cumsum(sel, axis=1) - 1.0) * sel                 # queue slot
-    keep = sel * (pos < cap)
-    disp = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)
-    disp = disp * keep[..., None]                               # (G,T,E,C)
-    combine = gates_full[..., None] * disp
 
-    # Switch-style load-balance aux loss
-    me = jnp.mean(sel, axis=1)
-    ce = jnp.mean(probs, axis=1)
-    aux = jnp.mean(jnp.sum(me * ce, axis=-1)) * e
+def _held_index(r: Routing, lo, held: int) -> jax.Array:
+    """(N·k,) each pair's place among the held experts [lo, lo + held),
+    ``held`` for a pair bound for an absent expert."""
+    e = r.experts.reshape(-1)
+    return jnp.where((e >= lo) & (e < lo + held), e - lo, held)
 
-    xin = jnp.einsum("gtec,gtd->gecd", disp.astype(x.dtype), tokens,
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    xin = shard_constraint(xin, (None, "activation_exp", None, None))
-    hg = jnp.einsum("gecd,edf->gecf", xin, p["w_gate"],
-                    preferred_element_type=jnp.float32)
-    hu = jnp.einsum("gecd,edf->gecf", xin, p["w_up"],
-                    preferred_element_type=jnp.float32)
-    hh = (jax.nn.silu(hg) * hu).astype(x.dtype)
-    out_e = jnp.einsum("gecf,efd->gecd", hh, p["w_down"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-    y = jnp.einsum("gtec,gecd->gtd", combine.astype(x.dtype), out_e,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
 
-    y = y.reshape(padded, d)[:n]
-    y = y.reshape(b, s, d)
+def dispatch(r: Routing, lo, held: int) -> Dispatch:
+    """Sort the (token, slot) pairs by held expert ([lo, lo + held)),
+    stable, with the pairs bound for an absent expert last.  ``lo`` may be
+    traced: an expert-parallel shard's first expert."""
+    k = r.experts.shape[-1]
+    local = _held_index(r, lo, held)
+    order = jnp.argsort(local, stable=True)
+    group_sizes = jnp.bincount(local, length=held + 1)[:held]
+    weights = jnp.where(local < held, r.weights.reshape(-1), 0.0)[order]
+    return Dispatch(order, order // k, weights, group_sizes.astype(jnp.int32))
+
+
+def _grouped(lhs: jax.Array, rhs: jax.Array, d: Dispatch,
+             grouped: jax.Array) -> jax.Array:
+    """``ragged_dot`` of the sorted pairs' rows by their experts' weights,
+    f32.  Rows past the held pairs belong to no group, and the grouped
+    matmul leaves them undefined, forward and in its lhs gradient (the
+    TPU's kernel does): the rows are zeroed on the way in and on the way
+    out, so that neither direction lets them reach a token."""
+    lhs = jnp.where(grouped[:, None], lhs, 0)
+    out = lax.ragged_dot(lhs, rhs.astype(lhs.dtype), d.group_sizes,
+                         preferred_element_type=jnp.float32)
+    return jnp.where(grouped[:, None], out, 0.0)
+
+
+def held_experts(p: Params, x: jax.Array, d: Dispatch, n_tokens: int,
+                 cfg: ModelConfig) -> jax.Array:
+    """Σ over each token's slots held here of weight × SwiGLU_expert(x):
+    (N, d) in x's dtype.  x: (N, d)."""
+    k = cfg.experts_per_token
+    with jax.named_scope(obs.MOE_ROUTE):
+        xs = x[d.tokens]                                  # (N·k, d)
+        grouped = jnp.arange(xs.shape[0]) < jnp.sum(d.group_sizes)
+    with jax.named_scope(obs.MOE_EXPERTS):
+        gate = _grouped(xs, p["w_gate"], d, grouped)
+        up = _grouped(xs, p["w_up"], d, grouped)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out = _grouped(h, p["w_down"], d, grouped)
+    with jax.named_scope(obs.MOE_ROUTE):
+        out = out * d.weights[:, None]
+        # back to (token, slot) order, then sum each token's slots
+        inverse = jnp.argsort(d.order)
+        y = jnp.sum(out[inverse].reshape(n_tokens, k, -1), axis=1)
+    return y.astype(x.dtype)
+
+
+def _routed(p: Params, tokens: jax.Array, r: Routing, cfg: ModelConfig):
+    """The held experts' part of the routed output, (N, d), and the pairs
+    routed to each held expert."""
+    lo, hi = cfg.held_experts
+    with jax.named_scope(obs.MOE_ROUTE):
+        d = dispatch(r, lo, hi - lo)
+    return held_experts(p, tokens, d, tokens.shape[0], cfg), d.group_sizes
+
+
+def _routed_expert_parallel(p: Params, tokens: jax.Array, r: Routing,
+                            axes: tuple[str, ...], batch: int,
+                            cfg: ModelConfig):
+    """``_routed`` with the held experts split over the mesh ``axes`` as
+    their weights are, under ``shard_map`` over the whole mesh: each
+    device computes its own experts' part for its tokens (those of its
+    batch rows, whole over ``axes``), and a sum over ``axes`` adds the
+    parts, as the parts of disjoint held ranges add up to the layer."""
+    lo, hi = cfg.held_experts
+    experts = {k: p[k] for k in ("w_gate", "w_up", "w_down")}
+    rows = tuple(a for a in split_axes("activation_batch", batch)
+                 if a not in axes)
+
+    def part(w, tokens, r):
+        # each device of ``axes`` reads every one of these tokens and
+        # routing slots: marked varying, so that their gradients are
+        # summed over the axes (a gather from an operand left invariant
+        # drops the other devices' parts)
+        tokens, r = lax.pcast((tokens, r), axes, to="varying")
+        held = w["w_gate"].shape[0]
+        first = lo + lax.axis_index(axes) * held
+        with jax.named_scope(obs.MOE_ROUTE):
+            d = dispatch(r, first, held)
+        y = held_experts(w, tokens, d, tokens.shape[0], cfg)
+        with jax.named_scope(obs.MOE_ROUTE):
+            return lax.psum(y.astype(jnp.float32), axes).astype(y.dtype)
+
+    y = jax.shard_map(part, in_specs=(P(axes), P(rows), P(rows)),
+                      out_specs=P(rows))(experts, tokens, r)
+    with jax.named_scope(obs.MOE_ROUTE):
+        counts = jnp.bincount(_held_index(r, lo, hi - lo),
+                              length=hi - lo + 1)[:hi - lo]
+    return y, counts.astype(jnp.int32)
+
+
+def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig):
+    """x: (B, S, d) -> (y, aux_loss, tokens per held expert).
+
+    y is the held experts' part of the routed output plus the shared
+    experts' whole output.  Under a mesh that splits the experts
+    (``sharding.split_axes("experts", ...)``) the held experts run
+    expert-parallel."""
+    b, s, dm = x.shape
+    tokens = x.reshape(b * s, dm)
+    with jax.named_scope(obs.MOE_ROUTE):
+        r = route(p["router"], tokens, cfg)
+        aux = sequence_aux_loss(r, b, cfg)
+    lo, hi = cfg.held_experts
+    axes = split_axes("experts", hi - lo)
+    if axes:
+        y, counts = _routed_expert_parallel(p, tokens, r, axes, b, cfg)
+    else:
+        y, counts = _routed(p, tokens, r, cfg)
+    y = y.reshape(b, s, dm)
     if "shared" in p:
-        y = y + L.mlp(p["shared"], x)
+        with jax.named_scope(obs.MOE_SHARED):
+            y = y + L.mlp(jax.tree.map(lambda w: w.astype(x.dtype),
+                                       p["shared"]), x)
     y = shard_constraint(y, ("activation_batch", "residual_length",
                              "activation_embed"))
-    return y, aux
+    return y, aux, counts
 
 
 # ------------------------- block + assembly -------------------------------
@@ -157,7 +278,7 @@ def block_apply_aux(p: Params, h, positions, cfg: ModelConfig):
                     unroll=L.scan_unroll_of(cfg),
                     chunk_threshold=cfg.attn_chunk_threshold)
     h = h + a
-    y, aux = moe_mlp(p["moe"], L.rms_norm(p["ln2"], h, cfg.norm_eps), cfg)
+    y, aux, _ = moe_mlp(p["moe"], L.rms_norm(p["ln2"], h, cfg.norm_eps), cfg)
     return h + y, aux
 
 
@@ -171,7 +292,7 @@ def block_decode(p: Params, h, ck, cv, cache_len, positions, cfg: ModelConfig):
         positions, theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
         eps=cfg.norm_eps, window=cfg.attn_window)
     h = h + a
-    y, _ = moe_mlp(p["moe"], L.rms_norm(p["ln2"], h, cfg.norm_eps), cfg)
+    y, _, _ = moe_mlp(p["moe"], L.rms_norm(p["ln2"], h, cfg.norm_eps), cfg)
     return h + y, ck, cv
 
 

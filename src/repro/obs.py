@@ -10,8 +10,16 @@ Run the program under ``jax.profiler.trace`` and the profile holds:
   ``fp<i>.run`` (period i's layer), ``fp<i>.xfer`` (its transition: the
   all-gather, window select and reshape), ``loss``, ``grad_norm``,
   ``optimizer`` and ``pad`` (the kernel wrappers' edge padding and
-  slicing).  The backward pass inherits them as ``transpose(jvp(<scope>))``.
-  They are op metadata only: the compiled instructions do not change.
+  slicing).  The LM training step (``launch/steps.build_train_step``)
+  names ``embed`` (the token lookup), ``mla`` (latent attention: its norm,
+  projections, attention core and residual add), ``moe.route`` (router,
+  top-k, sort, gathers, combine and the balance loss), ``moe.experts``
+  (the grouped matmuls of the held experts), ``moe.shared`` (the shared
+  experts), ``dense_mlp`` (a dense layer's MLP), ``layers`` (the layer
+  scan's own slicing and loop work), ``loss``, ``grad_norm`` and
+  ``optimizer``.  The backward pass inherits them as
+  ``transpose(jvp(<scope>))``.  They are op metadata only: the compiled
+  instructions do not change.
 
 Counters are a plain dict of trace-time counts, read with ``counters()``:
 ``train_step.traces`` counts traces of ``Executable.train_step``'s body,
@@ -32,6 +40,14 @@ LOSS = "loss"
 GRAD_NORM = "grad_norm"
 OPTIMIZER = "optimizer"
 PAD = "pad"
+
+EMBED = "embed"
+MLA = "mla"
+MOE_ROUTE = "moe.route"
+MOE_EXPERTS = "moe.experts"
+MOE_SHARED = "moe.shared"
+DENSE_MLP = "dense_mlp"
+LAYERS = "layers"
 
 TRAIN_STEP_TRACES = "train_step.traces"
 

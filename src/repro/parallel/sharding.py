@@ -30,6 +30,7 @@ __all__ = [
     "replicate",
     "shard_constraint",
     "shard_stacked",
+    "split_axes",
     "tree_shardings",
 ]
 
@@ -45,7 +46,6 @@ _DEFAULT = {
     "activation_kv_heads": "model",
     "activation_mlp": "model",
     "activation_vocab": "model",
-    "activation_exp": "model",
     # weights
     "embed": "data",          # FSDP axis of weight matrices
     "vocab": "model",
@@ -190,6 +190,19 @@ def shard_stacked(tree: Any, mesh: Mesh, axis: str | None = None) -> Any:
         return jax.device_put(x, NamedSharding(mesh, spec))
 
     return jax.tree.map(put, tree)
+
+
+def split_axes(logical: str, size: int) -> tuple[str, ...]:
+    """The axes of the enclosing mesh (``use_abstract_mesh``) over which a
+    dimension of ``size`` named ``logical`` is split, as ``resolve_spec``
+    splits it under the active rules, leaving out axes of one device;
+    () outside a mesh."""
+    mesh = _current_mesh()
+    if mesh is None:
+        return ()
+    phys = resolve_spec((size,), (logical,), mesh, active_rules())[0]
+    names = (phys,) if isinstance(phys, str) else tuple(phys or ())
+    return tuple(a for a in names if mesh.shape[a] > 1)
 
 
 def _current_mesh() -> AbstractMesh | None:

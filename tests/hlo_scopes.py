@@ -1,5 +1,6 @@
 """The program scopes (``repro.obs``) named by compiled HLO text, for the
-scope-coverage tests of ``test_obs.py`` and ``test_tpu_compile.py``."""
+scope-coverage tests of ``test_obs.py`` and ``test_tpu_compile.py``: the
+FCNN period program's and the LM training step's."""
 
 from __future__ import annotations
 
@@ -7,7 +8,9 @@ import re
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TRANSFORM = re.compile(r"^[\w-]+\((.*)\)$")
-_SCOPE = re.compile(r"^(?:fp\d+\.(?:run|xfer)|loss|grad_norm|optimizer|pad)$")
+_SCOPE = re.compile(r"^(?:fp\d+\.(?:run|xfer)|loss|grad_norm|optimizer|pad"
+                    r"|embed|mla|moe\.(?:route|experts|shared)|dense_mlp"
+                    r"|layers)$")
 _XLA_NAMED = re.compile(r"^(?:shard_map|[a-z][\w-]*\.\d+)$")
 STEP = "jit(step)/"
 
@@ -58,3 +61,9 @@ def present(text: str) -> set[str]:
 # what every compiled training step names, forward and backward
 REQUIRED = {"optimizer", "grad_norm", "loss", "pad", "fp1.run",
             "bwd:fp1.run", "fp1.xfer"}
+
+# what the LM training step (``launch/steps.build_train_step``) of
+# DeepSeek-V2 names, forward and backward
+LM_REQUIRED = {"embed", "layers", "mla", "moe.route", "moe.experts",
+               "moe.shared", "dense_mlp", "loss", "grad_norm", "optimizer",
+               "bwd:mla", "bwd:moe.experts", "bwd:embed"}

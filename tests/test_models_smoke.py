@@ -73,10 +73,6 @@ def test_smoke_param_axes_structure(arch):
 @pytest.mark.parametrize("arch", list_archs())
 def test_smoke_prefill_decode_matches_forward(arch):
     cfg = smoke_config(arch)
-    if cfg.family == "moe":
-        # capacity dropping is grouping-dependent; disable drops for the
-        # consistency check
-        cfg = cfg.replace(capacity_factor=float(cfg.n_experts))
     model = get_model(cfg)
     params = model.init(KEY)
     batch, b, s = _batch_for(cfg)

@@ -1,7 +1,8 @@
 """The program's own names for its work (``repro.obs``): the ``Batcher``'s
 host spans as a profile records them, the ``train_step.traces`` counter,
 and the device scopes on every instruction of the compiled training step
-(one device and a four-device host ring, NN1 and NN6)."""
+(one device and a four-device host ring, NN1 and NN6; and the LM step of
+``launch/steps.build_train_step`` for DeepSeek-V2)."""
 
 import jax
 import jax.numpy as jnp
@@ -10,11 +11,15 @@ import pytest
 from jax.sharding import Mesh
 
 import repro.exec as rexec
-from hlo_scopes import REQUIRED, present, unscoped
+from hlo_scopes import LM_REQUIRED, REQUIRED, present, unscoped
 from repro import obs
+from repro.configs import smoke_config
+from repro.configs.base import ShapeSpec
 from repro.configs.nn_benchmarks import onoc_config, workload
 from repro.core.onoc_model import FCNNWorkload
 from repro.data import Batcher
+from repro.launch import steps
+from repro.models.api import get_model
 from repro.optim import adam
 
 
@@ -94,3 +99,23 @@ def test_every_step_instruction_names_a_program_scope(name, n_devices):
     last = len(workload(name, 128).layer_sizes) - 1
     assert {f"fp{last}.run", f"bwd:fp{last}.xfer", "bwd:loss"} <= present(
         text)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_every_lm_step_instruction_names_a_model_scope(remat):
+    """Each instruction of DeepSeek-V2's compiled training step names
+    ``embed``, ``layers``, ``mla``, ``moe.route``, ``moe.experts``,
+    ``moe.shared``, ``dense_mlp``, ``loss``, ``grad_norm`` or
+    ``optimizer``."""
+    cfg = smoke_config("deepseek-v2-lite").replace(remat=remat)
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    step, _, _, spec = steps.build_train_step(
+        get_model(cfg), mesh, ShapeSpec("t", 32, 2, "train"),
+        steps.TrainSettings())
+    batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+             for k in ("tokens", "labels")}
+    text = step.lower(spec, batch).compile().as_text()
+    assert unscoped(text) == []
+    assert LM_REQUIRED <= present(text)

@@ -50,6 +50,17 @@ def _decode_slot(runner: JaxModelRunner, streams: dict[int, list[int]],
 
 
 def test_mid_stream_admission_leaves_inflight_stream_unchanged(cfg):
+    _check_admission_isolation(cfg)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-moe-a2.7b", "granite-moe-1b-a400m"])
+def test_moe_mid_stream_admission_leaves_inflight_stream_unchanged(arch):
+    """The expert layer routes without capacity (models/moe.py), so a
+    slot's tokens do not depend on which requests share its batch."""
+    _check_admission_isolation(smoke_config(arch))
+
+
+def _check_admission_isolation(cfg):
     pa = _prompt(0, 8, cfg.vocab_size)
     pb = _prompt(1, 8, cfg.vocab_size)
 

@@ -1,7 +1,9 @@
-"""Compiles of the FCNN training path for a described TPU v5e, no chip
-attached: the TPU compiler installed with JAX compiles for a ``v5e:2x2``
-topology description, so what Mosaic or XLA would refuse on the chip is
-refused here.  Nothing runs; these tests say nothing about values or
+"""Compiles of the FCNN training path, of DeepSeek-V2's latent
+attention, held-expert layer and loss at published widths, and of an
+expert-parallel MoE training step on four chips, for a described TPU
+v5e, no chip attached: the TPU compiler installed with JAX compiles for
+a ``v5e:2x2`` topology description, so what Mosaic or XLA would refuse
+on the chip is refused here.  Nothing runs; these tests say nothing about values or
 times (tests/test_kernels.py checks values in interpret mode).
 
 All chip compiles live in this one file: the topology is described once,
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import (
+    AxisType,
     Mesh,
     NamedSharding,
     PartitionSpec as P,
@@ -32,7 +35,11 @@ from repro.kernels.fcnn_layer import (
     fcnn_layer_wgrad,
 )
 from repro.kernels.softmax_xent import softmax_xent_dlogits, softmax_xent_fwd
-from repro.models import fcnn
+from repro.configs import get_config, smoke_config
+from repro.configs.base import ShapeSpec
+from repro.launch import steps
+from repro.models.api import get_model
+from repro.models import deepseek_v2, fcnn, moe
 from repro.optim import adam
 
 # every (n_in, n_out) layer of the paper's NN1 and NN6
@@ -137,6 +144,95 @@ def test_softmax_xent_compiles(one_chip, kernel, batch):
         row = _sds((batch,), one_chip)
         lowered = softmax_xent_dlogits.lower(logits, labels, row, row)
     _compiled_text(lowered)
+
+
+# ----------------------------------------------- DeepSeek-V2 at its widths
+
+# one chip's share, as the benchmark's dsv2lite configuration cuts it
+DSV2 = get_config("deepseek-v2-lite").replace(
+    n_layers=5, vocab_size=12800, expert_range=(0, 8))
+DSV2_TOKENS = 2 * 4096
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_lm_loss_kernels_compile(one_chip, kernel):
+    """The softmax cross-entropy kernels over the vocabulary slice."""
+    logits = _sds((DSV2_TOKENS, DSV2.vocab_size), one_chip)
+    labels = _sds((DSV2_TOKENS,), one_chip, jnp.int32)
+    if kernel == "fwd":
+        lowered = softmax_xent_fwd.lower(logits, labels)
+    else:
+        row = _sds((DSV2_TOKENS,), one_chip)
+        lowered = softmax_xent_dlogits.lower(logits, labels, row, row)
+    _compiled_text(lowered)
+
+
+def _layer_shapes(tree, sharding):
+    return jax.tree.map(lambda s: _sds(s.shape[1:], sharding, s.dtype), tree)
+
+
+def test_held_expert_layer_grad_compiles(one_chip):
+    """8 held of 64 experts, top-6, 8,192 tokens: the routed pairs run as
+    XLA's grouped-matmul kernel."""
+    params = jax.eval_shape(lambda k: deepseek_v2.init(k, DSV2),
+                            jax.random.PRNGKey(0))
+    p = _layer_shapes(params["layers"]["moe"], one_chip)
+    x = _sds((2, 4096, DSV2.d_model), one_chip, jnp.bfloat16)
+
+    def f(p, x):
+        y, aux, _ = moe.moe_mlp(p, x, DSV2)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    text = _compiled_text(jax.jit(jax.grad(f, argnums=(0, 1))).lower(p, x))
+    assert "ragged-dot" in text
+
+
+def test_latent_attention_grad_compiles(one_chip):
+    """MLA at 4,096 positions on the kv-chunked path (q/k head dim 192,
+    v head dim 128)."""
+    params = jax.eval_shape(lambda k: deepseek_v2.init(k, DSV2),
+                            jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda s: _sds(s.shape, one_chip, jnp.bfloat16),
+                     _layer_shapes(params["dense"]["attn"], one_chip))
+    x = _sds((2, 4096, DSV2.d_model), one_chip, jnp.bfloat16)
+    positions = jnp.broadcast_to(jnp.arange(4096), (2, 4096))
+
+    def f(p, x):
+        return jnp.sum(deepseek_v2.mla(p, x, positions, DSV2).astype(
+            jnp.float32))
+
+    # no Pallas kernel here: XLA's own ops on the chunked path
+    jax.jit(jax.grad(f, argnums=(0, 1))).lower(p, x).compile()
+
+
+_RAGGED = re.compile(r"%ragged-dot\S* = \w+\[([\d,]+)\]")
+
+
+def test_expert_parallel_moe_step_compiles_on_four_chips(topo):
+    """qwen2-moe's training step (smoke widths, bf16) on a 2 x 2 data x
+    model mesh: its 6 experts are split 3 to a "model" device, each
+    device's grouped matmuls take only its batch rows' (token, slot)
+    pairs and its own 3 experts, and a sum over "model" adds the parts."""
+    cfg = smoke_config("qwen2-moe-a2.7b").replace(dtype="bfloat16")
+    model = get_model(cfg)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    shape = ShapeSpec("ep", 64, 4, "train")
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        step, st_sh, b_sh, spec = steps.build_train_step(model, mesh, shape)
+        place = lambda s, sh: _sds(s.shape, sh, s.dtype)  # noqa: E731
+        text = step.lower(
+            jax.tree.map(place, spec, st_sh),
+            jax.tree.map(place, model.input_specs(shape), b_sh),
+        ).compile().as_text()
+    assert st_sh["params"]["layers"]["moe"]["w_gate"].spec[1] == "model"
+    pairs = 4 * 64 // 2 * cfg.experts_per_token   # a data shard's pairs
+    shapes = [tuple(map(int, m.split(","))) for m in _RAGGED.findall(text)]
+    assert {len(s) for s in shapes} == {2, 3}     # forward/dgrad, wgrad
+    assert all(s[0] == pairs for s in shapes if len(s) == 2)
+    assert all(s[0] == cfg.n_experts // 2 for s in shapes if len(s) == 3)
+    assert re.search(r"all-reduce\(.*op_name=\"[^\"]*shard_map/moe\.route/"
+                     r"psum", text)
 
 
 # ------------------------------------------------------------- train step
