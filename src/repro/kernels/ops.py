@@ -47,6 +47,14 @@ itself.  Its Pallas modes also carry a ``jax.custom_vjp`` —
 
 Labels are integer class ids and get a ``None`` cotangent.
 
+flash_attention: causal attention for training
+----------------------------------------------
+``flash_attention``'s Pallas modes carry a ``jax.custom_vjp`` too: the
+forward kernel returns the output and each row's f32 log-sum-exp, the
+residuals are (q, k, v, o, lse), and the backward runs the dK/dV and dQ
+kernels of ``kernels/flash_attention.py``.  The scale is taken as given
+and v's head dim may differ from q's (latent attention).
+
 Block sizes & padding: kernels auto-select MXU-aligned blocks and
 zero-pad edge tiles, so non-128-divisible shapes (784, 10, …) are
 accepted in every mode; explicit ``block_m/n/k`` overrides act as
@@ -66,7 +74,10 @@ from repro.kernels.fcnn_layer import (
     fcnn_layer_dgrad as _fcnn_dgrad_pallas,
     fcnn_layer_wgrad as _fcnn_wgrad_pallas,
 )
-from repro.kernels.flash_attention import flash_attention as _flash_pallas
+from repro.kernels.flash_attention import (
+    flash_attention_bwd as _flash_bwd_pallas,
+    flash_attention_fwd as _flash_fwd_pallas,
+)
 from repro.kernels.softmax_xent import (
     softmax_xent_dlogits as _xent_dlogits_pallas,
     softmax_xent_fwd as _xent_fwd_pallas,
@@ -169,13 +180,39 @@ def softmax_xent(logits, labels, *, force: str | None = None, **blocks):
     return fused(logits, labels)
 
 
+@functools.lru_cache(maxsize=None)
+def _fused_flash(causal: bool, scale, interpret: bool, blocks: tuple):
+    """custom_vjp-wrapped flash attention for one static config."""
+    kw = dict(blocks, causal=causal, scale=scale, interpret=interpret)
+
+    @jax.custom_vjp
+    def f(q, k, v):
+        return _flash_fwd_pallas(q, k, v, **kw)[0]
+
+    def fwd(q, k, v):
+        o, lse = _flash_fwd_pallas(q, k, v, **kw)
+        return o, (q, k, v, o, lse)
+
+    def bwd(res, do):
+        return _flash_bwd_pallas(*res, do, **kw)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 def flash_attention(q, k, v, causal: bool = True, *,
-                    force: str | None = None, **blocks):
+                    scale: float | None = None, force: str | None = None,
+                    **blocks):
+    """softmax(q·kᵀ·scale) v.  q, k: (B, H, S, D); v: (B, H, S, Dv);
+    ``scale`` 1/√D where None.  Its Pallas modes carry a ``custom_vjp``:
+    the forward saves (q, k, v, o, lse), the backward runs the dK/dV and
+    dQ kernels."""
     mode = _mode(force)
     if mode == "ref":
-        return _ref.flash_attention_ref(q, k, v, causal)
+        return _ref.flash_attention_ref(q, k, v, causal, scale)
     interp = mode == "pallas_interpret"
-    return _flash_pallas(q, k, v, causal=causal, interpret=interp, **blocks)
+    fused = _fused_flash(causal, scale, interp, tuple(sorted(blocks.items())))
+    return fused(q, k, v)
 
 
 def ssd_chunk(x, dt_a, b, c, *, force: str | None = None, **blocks):

@@ -20,6 +20,7 @@ __all__ = [
     "softmax_xent_ref",
     "softmax_xent_dlogits_ref",
     "flash_attention_ref",
+    "flash_attention_lse_ref",
     "ssd_chunk_ref",
 ]
 
@@ -103,20 +104,36 @@ def softmax_xent_dlogits_ref(logits: jax.Array, labels: jax.Array,
     return ((p - onehot) * (g / logits.shape[0])).astype(logits.dtype)
 
 
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                        causal: bool = True) -> jax.Array:
-    """q, k, v: (B, H, S, D) -> (B, H, S, D), softmax in fp32."""
+def _attention_scores(q: jax.Array, k: jax.Array, causal: bool,
+                      scale: float | None) -> jax.Array:
+    """(B, H, Sq, Sk) f32 scores q·kᵀ·scale, masked to -inf above the
+    diagonal where ``causal``; ``scale`` 1/√D where None."""
     d = q.shape[-1]
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(d)
+                   preferred_element_type=jnp.float32) * scale
     if causal:
         sq, sk = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
         s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    return s
+
+
+def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                        causal: bool = True,
+                        scale: float | None = None) -> jax.Array:
+    """q, k: (B, H, S, D); v: (B, H, S, Dv) -> (B, H, S, Dv), softmax in
+    fp32; ``scale`` 1/√D where None."""
+    p = jax.nn.softmax(_attention_scores(q, k, causal, scale), axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     return o.astype(q.dtype)
+
+
+def flash_attention_lse_ref(q: jax.Array, k: jax.Array, causal: bool = True,
+                            scale: float | None = None) -> jax.Array:
+    """(B, H, S) f32 log-sum-exp of each row of the scores."""
+    return jax.nn.logsumexp(_attention_scores(q, k, causal, scale), axis=-1)
 
 
 def ssd_chunk_ref(x: jax.Array, dt_a: jax.Array, b: jax.Array, c: jax.Array):

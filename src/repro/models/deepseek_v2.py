@@ -44,6 +44,7 @@ from jax import lax
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.kernels.flash_attention import select_blocks as flash_blocks
 from repro.models import layers as L
 from repro.models import moe
 from repro.models import transformer as T
@@ -233,19 +234,37 @@ def _keys_values(p: Params, c_kv: jax.Array, k_pe: jax.Array,
     return jnp.concatenate([kv[..., :dn], k_pe], axis=-1), kv[..., dn:]
 
 
-def _out(p: Params, o: jax.Array, dtype) -> jax.Array:
-    return jnp.einsum("blhv,hvd->bld", o, p["wo"],
+def _out(p: Params, o: jax.Array, dtype, heads_first: bool = False):
+    """o (B, L, H, d_v), or (B, H, L, d_v) where ``heads_first``, times
+    W_o."""
+    spec = "bhlv,hvd->bld" if heads_first else "blhv,hvd->bld"
+    return jnp.einsum(spec, o, p["wo"],
                       preferred_element_type=L.pet()).astype(dtype)
 
 
 def mla(p: Params, x: jax.Array, positions, cfg: ModelConfig) -> jax.Array:
-    """Causal latent self-attention of x (B, L, d); p in x's dtype."""
+    """Causal latent self-attention of x (B, L, d); p in x's dtype.
+
+    The attention core runs in the Pallas flash kernel
+    (``ops.flash_attention``, heads first) where the kernels dispatch to
+    Pallas and a block divides L; else in the kv-chunked jnp scan above
+    ``cfg.attn_chunk_threshold`` score elements, and in one softmax below
+    it.  ``obs`` counts the calls traced onto the kernel and onto the
+    scan."""
     c_kv, k_pe = _latent(p, x, positions, cfg)
     q = _queries(p, x, positions, cfg)
     k, v = _keys_values(p, c_kv, k_pe, cfg)
     lq = q.shape[1]
     scale = softmax_scale(cfg)
+    mode = ops.resolve_mode(None)
+    if mode != "ref" and flash_blocks(lq, q.shape[-1], v.shape[-1],
+                                      q.dtype.itemsize) is not None:
+        obs.bump(obs.ATTENTION_FLASH)
+        o = ops.flash_attention(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)),
+                                causal=True, scale=scale, force=mode)
+        return _out(p, o, x.dtype, heads_first=True)
     if lq * lq > cfg.attn_chunk_threshold and lq % L._SDPA_CHUNK == 0:
+        obs.bump(obs.ATTENTION_CHUNKED)
         o = L._sdpa_chunked_causal(q, k, v, L._SDPA_CHUNK,
                                    L.scan_unroll_of(cfg), scale)
     else:

@@ -270,8 +270,9 @@ def _sdpa(q, k, v, mask, scale: float | None = None):
 
 # Above this many score elements per head-group, attention switches to the
 # kv-chunked online-softmax path (flash-style: O(L·chunk) live memory).
-# On TPU the Pallas kernel (kernels/flash_attention.py) takes this role;
-# the jnp scan below is its XLA-lowerable twin used by the dry-run.
+# On TPU, latent attention (models/deepseek_v2.mla) runs the Pallas kernel
+# (kernels/flash_attention.py) in its place; ``attention`` below and every
+# other model keep this jnp scan on every backend.
 _CHUNKED_SDPA_THRESHOLD = 4096 * 4096
 _SDPA_CHUNK = 1024
 

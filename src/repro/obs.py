@@ -24,6 +24,9 @@ Run the program under ``jax.profiler.trace`` and the profile holds:
 Counters are a plain dict of trace-time counts, read with ``counters()``:
 ``train_step.traces`` counts traces of ``Executable.train_step``'s body,
 so a value that grows after warm-up means the step recompiled.
+``attention.flash`` and ``attention.chunked`` count the latent-attention
+calls (``models/deepseek_v2.mla``) traced onto the Pallas flash kernel
+and onto the kv-chunked jnp scan.
 """
 
 from __future__ import annotations
@@ -50,6 +53,8 @@ DENSE_MLP = "dense_mlp"
 LAYERS = "layers"
 
 TRAIN_STEP_TRACES = "train_step.traces"
+ATTENTION_FLASH = "attention.flash"
+ATTENTION_CHUNKED = "attention.chunked"
 
 _counters: collections.Counter[str] = collections.Counter()
 

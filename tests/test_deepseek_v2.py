@@ -13,8 +13,10 @@ import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs import get_config, smoke_config
 from repro.configs.base import ShapeSpec
+from repro.kernels import ops
 from repro.launch import steps
 from repro.models import deepseek_v2 as dsv2
 from repro.models import layers as L
@@ -247,6 +249,41 @@ def test_chunked_attention_with_v_dim_and_scale_matches_sdpa():
     # no scale given: 1/sqrt(d), the path's old behaviour
     _close(L._sdpa_chunked_causal(q, k, q, 16, 1),
            L._sdpa_chunked_causal(q, k, q, 16, 1, 1 / np.sqrt(d)), 0.0)
+
+
+def test_mla_on_flash_kernel_matches_chunked_path(monkeypatch):
+    """mla at smoke widths and 1,024 positions (a multiple of the kernel's
+    blocks and of the scan's chunk) on the Pallas flash kernel, in
+    interpret mode, against mla on the kv-chunked scan: the output and the
+    gradients of the parameters and the input.  The counters name the
+    path each call was traced onto."""
+    cfg = CFG.replace(attn_chunk_threshold=16)
+    length = 1024
+    params = dsv2.init(KEY, cfg)
+    attn = jax.tree.map(lambda a: a[0], params["dense"]["attn"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, length, cfg.d_model))
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    positions = jnp.broadcast_to(jnp.arange(length), (2, length))
+
+    def run(mode):
+        monkeypatch.setattr(ops, "resolve_mode", lambda force: mode)
+        before = obs.counters()
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(lambda p, x: dsv2.mla(p, x, positions, cfg),
+                               attn, x)
+            grads = vjp(cot)
+        after = obs.counters()
+        traced = {n: after.get(n, 0) - before.get(n, 0)
+                  for n in (obs.ATTENTION_FLASH, obs.ATTENTION_CHUNKED)}
+        return out, grads, traced
+
+    got, got_grads, flash = run("pallas_interpret")
+    want, want_grads, chunked = run("ref")
+    assert flash == {obs.ATTENTION_FLASH: 1, obs.ATTENTION_CHUNKED: 0}
+    assert chunked == {obs.ATTENTION_FLASH: 0, obs.ATTENTION_CHUNKED: 1}
+    _close(got, want, 1e-4)
+    for a, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        _close(a, w, 1e-4)
 
 
 def _undefined_outside_groups(ragged_dot):

@@ -27,6 +27,7 @@ from jax.sharding import (
 )
 
 import repro.exec as rexec
+from repro import obs
 from hlo_scopes import REQUIRED, present, unscoped
 from repro.configs.nn_benchmarks import NN_BENCHMARKS, onoc_config, workload
 from repro.kernels.fcnn_layer import (
@@ -34,6 +35,8 @@ from repro.kernels.fcnn_layer import (
     fcnn_layer_dgrad,
     fcnn_layer_wgrad,
 )
+from repro.kernels import ops
+from repro.kernels.flash_attention import select_blocks as flash_select_blocks
 from repro.kernels.softmax_xent import softmax_xent_dlogits, softmax_xent_fwd
 from repro.configs import get_config, smoke_config
 from repro.configs.base import ShapeSpec
@@ -187,9 +190,16 @@ def test_held_expert_layer_grad_compiles(one_chip):
     assert "ragged-dot" in text
 
 
-def test_latent_attention_grad_compiles(one_chip):
-    """MLA at 4,096 positions on the kv-chunked path (q/k head dim 192,
-    v head dim 128)."""
+_FLASH_CALL = re.compile(r"%(flash_attention_[a-z]+)[.\d]* = .*tpu_custom_call")
+
+
+def test_latent_attention_grad_compiles(one_chip, monkeypatch):
+    """MLA at 4,096 positions (q/k head dim 192, v head dim 128) with the
+    kernels dispatched to Pallas, as on the chip: Mosaic takes the flash
+    kernels at the blocks ``select_blocks`` chooses, and they run under
+    the ``mla`` scope, forward and backward, so ``mla_ms`` holds the
+    attention core."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     params = jax.eval_shape(lambda k: deepseek_v2.init(k, DSV2),
                             jax.random.PRNGKey(0))
     p = jax.tree.map(lambda s: _sds(s.shape, one_chip, jnp.bfloat16),
@@ -198,11 +208,28 @@ def test_latent_attention_grad_compiles(one_chip):
     positions = jnp.broadcast_to(jnp.arange(4096), (2, 4096))
 
     def f(p, x):
-        return jnp.sum(deepseek_v2.mla(p, x, positions, DSV2).astype(
-            jnp.float32))
+        with jax.named_scope(obs.MLA):
+            return jnp.sum(deepseek_v2.mla(p, x, positions, DSV2).astype(
+                jnp.float32))
 
-    # no Pallas kernel here: XLA's own ops on the chunked path
-    jax.jit(jax.grad(f, argnums=(0, 1))).lower(p, x).compile()
+    def step(p, x):
+        return jax.grad(f, argnums=(0, 1))(p, x)
+
+    assert flash_select_blocks(4096, 192, 128, 2) == (1024, 1024)
+    before = obs.counters()
+    text = _compiled_text(jax.jit(step).lower(p, x))
+    after = obs.counters()
+    assert after.get(obs.ATTENTION_FLASH, 0) == before.get(
+        obs.ATTENTION_FLASH, 0) + 1
+    assert after.get(obs.ATTENTION_CHUNKED) == before.get(
+        obs.ATTENTION_CHUNKED)
+    scopes = {}
+    for line in text.splitlines():
+        if (m := _FLASH_CALL.search(line)):
+            scopes.setdefault(m.group(1), set()).update(present(line))
+    assert "mla" in scopes["flash_attention_fwd"]
+    assert "bwd:mla" in scopes["flash_attention_dkv"]
+    assert "bwd:mla" in scopes["flash_attention_dq"]
 
 
 _RAGGED = re.compile(r"%ragged-dot\S* = \w+\[([\d,]+)\]")
