@@ -1,25 +1,20 @@
-"""Static analyzer benchmark (ISSUE 9): per-device analysis wall time at
-both levels (``fast``: expansion + endpoints + happens-before + memory;
-``full``: + cost contract + shape abstract interpretation) for every
-paper benchmark on the 8-device ring, plus the corruption-corpus
-regression row — every seeded corruption must pass the SPMD validator
-(its blind spot) yet be rejected by the analyzer with the expected
-message."""
+"""Static checker sweep: every paper benchmark's compiled program on the
+8-device ring checks clean (with its size: instructions, per-device ops,
+happens-before edges), and the corruption-corpus regression row — every
+seeded corruption must be rejected with the expected message."""
 
 from __future__ import annotations
 
 import re
-import time
 
 from repro.configs.nn_benchmarks import NN_BENCHMARKS, onoc_config, workload
 from repro.core.allocation import MappingStrategy
-from repro.exec.analysis import (
-    ProgramAnalysisError,
-    analyze_program,
+from repro.exec.check import (
+    ProgramValidationError,
+    check_program,
     corruption_corpus,
 )
 from repro.exec.program import compile_fcnn_program
-from repro.exec.validate import ProgramValidationError, validate_program
 
 N_DEV = 8
 
@@ -31,17 +26,9 @@ def run() -> list[dict]:
         w = workload(nn, batch_size=64)
         prog = compile_fcnn_program(w, cfg, N_DEV, MappingStrategy.ORRM)
         try:
-            t0 = time.perf_counter()
-            analyze_program(prog, level="fast")
-            fast_us = 1e6 * (time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            report = analyze_program(prog, w, cfg, level="full")
-            full_us = 1e6 * (time.perf_counter() - t0)
-            clean = True
+            report = check_program(prog, w, cfg)
         except ProgramValidationError:
-            fast_us = full_us = float("nan")
             report = None
-            clean = False
         rows.append({
             "case": f"{nn.lower()}_orrm",
             "nn": nn,
@@ -50,35 +37,25 @@ def run() -> list[dict]:
             "instructions": 0 if report is None else report.n_instructions,
             "device_ops": 0 if report is None else report.n_device_ops,
             "hb_edges": 0 if report is None else report.n_hb_edges,
-            "analyze_fast_us": fast_us,
-            "analyze_full_us": full_us,
-            "clean": clean,
+            "clean": report is not None,
         })
 
-    # the corpus regression: derived from the NN1 program, each entry in a
-    # validator blind spot (validator_passes) and analyzer-rejected with
-    # the expected message (analyzer_rejects)
+    # the corpus regression: derived from the NN1 program, each entry
+    # rejected with the expected message
     w = workload("NN1", batch_size=64)
     prog = compile_fcnn_program(w, cfg, N_DEV, MappingStrategy.ORRM)
     entries = corruption_corpus(prog, seed=0)
-    validator_passes = analyzer_rejects = 0
+    rejected = 0
     for e in entries:
         try:
-            validate_program(e.program, w, cfg)
-            validator_passes += 1
-        except ProgramValidationError:
-            pass
-        try:
-            analyze_program(e.program, w, cfg, level="full")
-        except ProgramAnalysisError as err:
+            check_program(e.program, w, cfg)
+        except ProgramValidationError as err:
             if re.search(e.match, str(err)):
-                analyzer_rejects += 1
+                rejected += 1
     rows.append({
         "case": "corruption_corpus",
         "n_entries": len(entries),
-        "validator_passes": validator_passes,
-        "analyzer_rejects": analyzer_rejects,
-        "corpus_ok": bool(validator_passes == len(entries)
-                          and analyzer_rejects == len(entries)),
+        "rejected": rejected,
+        "corpus_ok": rejected == len(entries),
     })
     return rows

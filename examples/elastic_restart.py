@@ -28,8 +28,8 @@ compiled period program's instruction list each step and lets the
 Replan-resume flow (scenario 3 below, also the CI ``fault-smoke`` job):
 on ``DeviceLossFault`` the runner asks ``ElasticPlanner.replan_program``
 for the Lemma-1 allocation on the survivors, recompiles the period
-program for the shrunken ring (statically re-validated by
-``exec.validate``), rebuilds the mesh + executor, and re-enters
+program for the shrunken ring (checked by ``exec.check`` as it is
+compiled), rebuilds the mesh + executor, and re-enters
 ``TrainingSupervisor`` — which restores the latest complete checkpoint
 (params, optimizer state *and* Batcher position, so no sample is skipped
 or repeated) and resumes.  Because executor numerics are device-count

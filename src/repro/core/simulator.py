@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Protocol
+from typing import Iterable, Protocol
 
 import numpy as np
 
@@ -43,7 +43,18 @@ __all__ = [
     "ENoCConfig",
     "ENoCBackend",
     "simulate_epoch",
+    "epoch_total",
 ]
+
+
+def epoch_total(costs: Iterable[float]) -> float:
+    """Sum per-period or per-transition seconds into an epoch total.
+
+    ``EpochTrace`` and ``exec.program.PeriodProgram`` both total their
+    costs here, so the program's cost contract holds bit for bit: the
+    same values summed the same way.
+    """
+    return float(sum(costs))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,7 +361,6 @@ def simulate_epoch(
         busy[list(mapping.window(i))] += f
 
     transitions: list[TransitionTraffic] = []
-    comm_total = 0.0
     for i in range(1, 2 * l):   # period 2l is excluded by the range itself
         if i == l:
             continue
@@ -362,13 +372,12 @@ def simulate_epoch(
             # hand-off into Period 0 loading.  Record traffic, zero time.
             tr = dataclasses.replace(tr, comm_s=0.0)
         transitions.append(tr)
-        comm_total += tr.comm_s
 
     return EpochTrace(
         backend=backend.name,
         strategy=mapping.strategy.value,
-        compute_s=float(sum(per_period_compute)),
-        comm_s=float(comm_total),
+        compute_s=epoch_total(per_period_compute),
+        comm_s=epoch_total(tr.comm_s for tr in transitions),
         transitions=tuple(transitions),
         per_period_compute_s=tuple(per_period_compute),
         core_busy_s=busy,

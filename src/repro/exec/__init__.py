@@ -18,13 +18,11 @@ this package existed the repo only *priced* those schedules
     PR-6 full-model oracle).
   * ``exec.residency`` — per-device live-bytes accounting over the
     program's schema-v2 residency annotations.
-  * ``exec.validate``  — static verifier: schedule invariants, the
-    residency byte ledger, and the cost contract vs the simulator.
-  * ``exec.analysis``  — the per-device static analyzer (ISSUE 9):
-    expands the SPMD program into one stream per device and checks
-    happens-before (deadlocks, endpoints), chunk-granular memory safety
-    and shape/dtype abstract interpretation; runs at compile time
-    (``compile(analyze=...)``) and after every replan.
+  * ``exec.check``     — the static checker, ``check_program``: schedule
+    invariants, the per-device streams (deadlocks, endpoints,
+    chunk-granular memory safety), shapes and the cost contract vs the
+    simulator.  ``compile_program`` runs it on every program it emits,
+    so each compile and each replan checks its program once.
   * ``exec.api``      — the façade: ``repro.exec.compile(workload, cfg,
     mesh, strategy=..., residency=...) -> Executable`` with
     ``.train_step()`` / ``.loss_fn()`` / ``.program`` / ``.degrade()``,
@@ -34,10 +32,10 @@ this package existed the repo only *priced* those schedules
 See exec/README.md for the API and dispatch rules.
 """
 
-from repro.exec.analysis import (  # noqa: F401
-    AnalysisReport,
-    ProgramAnalysisError,
-    analyze_program,
+from repro.exec.check import (  # noqa: F401
+    CheckReport,
+    ProgramValidationError,
+    check_program,
     corruption_corpus,
     expand_program,
 )
@@ -59,17 +57,13 @@ from repro.exec.runtime import (  # noqa: F401
     ProgramExecutor,
     build_train_step,
 )
-from repro.exec.validate import (  # noqa: F401
-    ProgramValidationError,
-    validate_program,
-)
 
 __all__ = [
     "compile",
     "Executable",
-    "AnalysisReport",
-    "ProgramAnalysisError",
-    "analyze_program",
+    "CheckReport",
+    "ProgramValidationError",
+    "check_program",
     "corruption_corpus",
     "expand_program",
     "Opcode",
@@ -80,6 +74,4 @@ __all__ = [
     "compile_fcnn_program",
     "ProgramExecutor",
     "build_train_step",
-    "ProgramValidationError",
-    "validate_program",
 ]

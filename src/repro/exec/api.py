@@ -1,7 +1,7 @@
-"""One-call plan -> compile -> validate -> execute façade (ISSUE 8).
+"""One-call plan -> compile -> check -> execute façade.
 
 The PR-6/7 surface scattered the pipeline over four entry points
-(``compile_fcnn_program`` -> ``validate_program`` -> ``ProgramExecutor``
+(``compile_fcnn_program`` -> the static checker -> ``ProgramExecutor``
 -> ``build_fcnn_program_step`` / ``build_train_step``), each with its own
 params-layout assumptions.  The weight-sharded residency path changes that
 layout contract end to end, so this module collapses the chain into:
@@ -46,7 +46,7 @@ __all__ = ["Executable", "compile"]
 
 @dataclasses.dataclass
 class Executable:
-    """A compiled, validated, mesh-bound period program ready to train.
+    """A compiled, checked, mesh-bound period program ready to train.
 
     Produced by ``repro.exec.compile`` (or ``from_program`` when the
     ``PeriodProgram`` already exists, e.g. deserialized or replanned).
@@ -72,16 +72,11 @@ class Executable:
                      workload: FCNNWorkload | None = None,
                      cfg: ONoCConfig | None = None,
                      plan: FCNNPlan | None = None,
-                     backend: Any = None,
-                     analyze: str = "off") -> "Executable":
-        """Bind an existing program to ``mesh``.  ``analyze`` defaults to
-        ``"off"`` because ``repro.exec.compile`` and the degraded-mode
-        replan path analyze before binding; pass ``"fast"``/``"full"``
-        for programs from untrusted sources (deserialized files)."""
-        if analyze != "off":
-            from repro.exec.analysis import analyze_program
-            analyze_program(program, workload, cfg, backend=backend,
-                            level=analyze)
+                     backend: Any = None) -> "Executable":
+        """Bind an existing program to ``mesh``.  Nothing is checked here:
+        ``compile_program`` checked every program it made; call
+        ``exec.check.check_program`` first on a program from anywhere
+        else (a deserialized file)."""
         ex = ProgramExecutor(program, mesh, kernel_mode=kernel_mode,
                              residency=residency)
         return cls(program=program, mesh=mesh, executor=ex,
@@ -182,28 +177,16 @@ def compile(  # noqa: A001 — deliberate façade name, repro.exec.compile
     residency: str = "sharded",
     backend: Any = None,
     kernel_mode: str | None = None,
-    analyze: str = "full",
 ) -> Executable:
-    """Plan (Lemma 1 on the divisor-complete ring), compile + statically
-    validate the period program, and bind it to ``mesh`` as an
-    ``Executable`` in the requested residency mode — the single entry
-    point replacing the compile_fcnn_program / validate_program /
+    """Plan (Lemma 1 on the divisor-complete ring), compile the period
+    program (``compile_program`` checks it, once), and bind it to ``mesh``
+    as an ``Executable`` in the requested residency mode — the single
+    entry point replacing the compile_fcnn_program / checker /
     ProgramExecutor / build_*_step chain.
-
-    ``analyze`` selects the static-analysis level (``exec.analysis``)
-    run on the compiled program before it is bound: ``"full"`` (default)
-    adds the per-device happens-before/memory checks and the shape
-    abstract interpreter on top of the validator; ``"fast"`` skips the
-    shape interpreter and the cost contract; ``"off"`` leaves only the
-    validator built into ``compile_program``.
     """
     n = mesh.devices.size
     plan = plan_fcnn(workload, cfg, ring_mesh_axes(n), strategy=strategy)
     program = compile_program(plan, workload, cfg, n, backend=backend)
-    if analyze != "off":
-        from repro.exec.analysis import analyze_program
-        analyze_program(program, workload, cfg, backend=backend,
-                        level=analyze)
     return Executable.from_program(
         program, mesh, residency=residency, kernel_mode=kernel_mode,
         workload=workload, cfg=cfg, plan=plan, backend=backend)

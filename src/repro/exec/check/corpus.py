@@ -1,9 +1,10 @@
-"""Seeded corruption corpus: programs the validator passes but the
-analyzer must reject.
+"""Seeded corruption corpus: programs the checker must reject although
+every set-level rule passes them.
 
-Each corruption targets a blind spot of ``validate_program``'s SPMD-level
-set/ledger checks (which are order-insensitive within a period and never
-read ``sources``, ``batch_size`` or ``activation``):
+Each corruption targets a blind spot of the set rules in
+``spmd.check_spmd`` (order-insensitive within a period; they never read
+``batch_size`` or ``activation``), so only the per-device streams, the
+chunk order of a RECV's ``sources`` or the shape rules catch it:
 
   * ``deadlocked-send-cycle``   — swap a transition period's SEND and
     RECV in the instruction stream.  Any device in both windows then
@@ -17,14 +18,14 @@ read ``sources``, ``batch_size`` or ``activation``):
   * ``free-before-last-use``    — move one leaving device's window FREE
     before the same period's SEND, on that device only: its stream frees
     the activation chunk the SEND is about to read (use-after-FREE).
-  * ``shape-mismatched-run``    — corrupt ``batch_size`` (the validator
-    prices costs from the workload argument, never from the program's
-    own batch) and, separately, flip a hidden-layer RUN's activation
-    annotation — both caught only by the shape abstract interpreter.
+  * ``shape-mismatched-run``    — corrupt ``batch_size`` (the cost rules
+    price from the workload argument, never from the program's own
+    batch) and, separately, flip a hidden-layer RUN's activation
+    annotation — both caught only by ``shapes.check_shapes``.
 
 ``corruption_corpus`` derives all of them from one valid program with a
 seeded RNG (reproducible; the seed picks among eligible periods), and
-every entry records the regex its ``ProgramAnalysisError`` must match.
+every entry records the regex its ``ProgramValidationError`` must match.
 """
 
 from __future__ import annotations
@@ -44,7 +45,7 @@ class CorruptedProgram:
     name: str
     description: str
     program: PeriodProgram
-    match: str          # regex the ProgramAnalysisError message must match
+    match: str          # regex the ProgramValidationError message must match
 
 
 def _with_instrs(program: PeriodProgram, instrs) -> PeriodProgram:
@@ -134,8 +135,8 @@ def _shape_mismatched_batch(program, rng) -> CorruptedProgram:
     return CorruptedProgram(
         name="shape-mismatched-run-batch",
         description=(f"batch_size corrupted {program.batch_size} -> "
-                     f"{program.batch_size * factor}; the validator prices "
-                     f"costs from the workload argument and never reads it"),
+                     f"{program.batch_size * factor}; the cost rules price "
+                     f"from the workload argument and never read it"),
         program=dataclasses.replace(
             program, batch_size=program.batch_size * factor),
         match="batch",

@@ -19,8 +19,8 @@ chunk.  ``expand_program`` performs it, lowering the program into one
     serialized before the annotation existed).
 
 The expansion itself is deliberately mechanical — all judgement lives in
-the checkers (``hb``: deadlocks/endpoints/memory, ``shapes``: abstract
-interpretation) that consume the streams.
+the checkers that consume the streams (``hb``: deadlocks and memory).
+``spmd.check_spmd`` runs first, so every device id is on the mesh.
 """
 
 from __future__ import annotations

@@ -41,8 +41,8 @@ FREE (``layer`` set, ``param_bytes`` set) scheduled immediately after the
 chunk's last use, the layer's BP mirror period 2l-i+1 (Eq. 11).  The
 original window FREEs (``layer`` is None) keep their PR-6 meaning: a
 device leaving the *active* window drops its activations but keeps its
-weight chunks for BP.  ``exec.validate`` checks the byte ledger drains to
-exactly zero and that no RUN executes on freed chunks;
+weight chunks for BP.  ``exec.check`` checks that every chunk is freed
+exactly once on every device and that no RUN executes on freed chunks;
 ``exec.residency.ResidencyTracker`` turns the annotations into a
 per-device live-bytes timeline.
 """
@@ -62,7 +62,7 @@ from repro.core.onoc_model import (
     period_layer,
 )
 from repro.core.planner import FCNNPlan, plan_fcnn, ring_mesh_axes
-from repro.core.simulator import ONoCBackend
+from repro.core.simulator import ONoCBackend, epoch_total
 from repro.models.fcnn import period_activation
 
 __all__ = [
@@ -117,8 +117,8 @@ class Instruction:
     param_bytes: float = 0.0
     # RECV endpoint annotation: chunk j of the gathered activation comes
     # from device ``sources[j]`` — the chunk-ordered sender window of the
-    # matching SEND.  Empty on pre-analysis programs (the analyzer then
-    # derives it from the SEND at the same period).
+    # matching SEND.  Empty on programs serialized before the annotation
+    # (the checker then derives it from the SEND at the same period).
     sources: tuple[int, ...] = ()
 
     @classmethod
@@ -193,12 +193,12 @@ class PeriodProgram:
     @property
     def compute_s(self) -> float:
         """Paper-level epoch compute — equals EpochTrace.compute_s."""
-        return float(sum(i.cost_s for i in self.runs()))
+        return epoch_total(i.cost_s for i in self.runs())
 
     @property
     def comm_s(self) -> float:
         """Paper-level epoch comm — equals EpochTrace.comm_s."""
-        return float(sum(i.cost_s for i in self.sends()))
+        return epoch_total(i.cost_s for i in self.sends())
 
     @property
     def total_s(self) -> float:
@@ -216,7 +216,7 @@ class PeriodProgram:
         """The instructions that involve ``device``, in program order.
 
         This is the raw per-device *view* (the SPMD instruction filtered
-        by membership in ``devices``); ``exec.analysis.expand_program``
+        by membership in ``devices``); ``exec.check.expand_program``
         lowers it further into concrete per-device ops with resolved
         chunk indices and SEND/RECV endpoints.
         """
@@ -252,7 +252,7 @@ class PeriodProgram:
     def from_json(cls, s: str) -> "PeriodProgram":
         """Load a serialized program.  v1 (PR 6, no residency annotations)
         loads with zeroed ``param_bytes`` and no param FREEs — the
-        validator skips the residency ledger for version < 2, and the
+        checker skips the residency rules for version < 2, and the
         sharded executor refuses such programs (recompile to upgrade)."""
         d = json.loads(s)
         version = d.get("version")
@@ -297,7 +297,6 @@ def compile_program(
     cfg: ONoCConfig,
     n_devices: int,
     backend=None,
-    validate: bool = True,
 ) -> PeriodProgram:
     """Lower a planner plan + its mapping into a PeriodProgram.
 
@@ -307,12 +306,12 @@ def compile_program(
     m=n_devices) supplies the executor windows, so FM/RRM/ORRM remapping is
     *executed*, not just priced.
 
-    Every emitted program is statically verified (``exec.validate``) before
-    it is returned — schedule invariants plus the cost contract against the
-    simulator — so a miscompiled or corrupted schedule is a hard error at
-    compile time, never silent wrong numerics at execution time.  Pass
-    ``validate=False`` only to construct intentionally-broken programs
-    (validator tests).
+    Every emitted program is checked (``exec.check.check_program``) before
+    it is returned — schedule invariants, the per-device streams, shapes
+    and the cost contract against the simulator — so a miscompiled
+    schedule is a hard error at compile time, never silent wrong numerics
+    at execution time.  This is the one place a compiled program is
+    checked.
     """
     backend = backend or ONoCBackend()
     l = workload.l
@@ -392,9 +391,8 @@ def compile_program(
         degrees=degrees,
         instructions=tuple(instrs),
     )
-    if validate:
-        from repro.exec.validate import validate_program
-        validate_program(program, workload, cfg, backend=backend)
+    from repro.exec import check  # exec.check imports this module
+    check.check_program(program, workload, cfg, backend=backend)
     return program
 
 

@@ -18,7 +18,7 @@ Two modes mirror the two executor paths (see exec/runtime.py):
     for the whole epoch; FREE is a cost annotation, nothing is released.
 
 The tracker is pure accounting over program annotations — it does not
-execute anything.  ``exec.validate`` separately checks the annotations
+execute anything.  ``exec.check`` separately checks the annotations
 themselves are consistent (bytes match geometry, FREEs sit at the mirror
 periods, no RUN touches freed chunks).
 """

@@ -17,8 +17,8 @@ prices or detects:
      trains on the reference path unnoticed;
   4. a ``DeviceLossFault`` is fatal to the current mesh: the runner asks
      ``ElasticPlanner.replan_program`` for the Lemma-1 plan on the
-     survivors, re-validates and recompiles the period program for the
-     shrunken ring, rebuilds the mesh + executor, and re-enters the
+     survivors, recompiles the period program for the shrunken ring
+     (``compile_program`` checks it before it is returned), rebuilds the mesh + executor, and re-enters the
      supervisor — which restores the latest complete checkpoint
      (including ``Batcher`` state, so no sample is skipped or repeated)
      and resumes training where it left off.
@@ -47,7 +47,6 @@ from repro.checkpoint import Checkpointer
 from repro.core.allocation import MappingStrategy
 from repro.core.onoc_model import FCNNWorkload, ONoCConfig
 from repro.exec.runtime import ProgramExecutor
-from repro.exec.validate import validate_program
 from repro.launch.mesh import make_test_mesh
 from repro.optim.optimizers import Optimizer
 from repro.parallel.sharding import replicate
@@ -92,7 +91,6 @@ class DegradedModeRunner:
     kernel_mode: str | None = None
     residency: str = "replicated"
     backend: Any = None
-    analyze: str = "full"               # exec.analysis level per rebuild
     checkpoint_every: int = 2
     max_retries: int = 3
     backoff_s: float = 0.01
@@ -118,18 +116,13 @@ class DegradedModeRunner:
         return make_test_mesh(n_devices)
 
     def _build(self, n_devices: int) -> None:
-        """(Re)plan, recompile, re-validate and rebuild mesh + executor +
-        jitted step for ``n_devices`` survivors."""
+        """(Re)plan, recompile (which checks the program) and rebuild mesh
+        + executor + jitted step for ``n_devices`` survivors.  A replanned
+        program for a shrunken ring is where a schedule bug would surface
+        first: it raises ``ProgramValidationError`` here, before anything
+        is bound or executed."""
         cfg, plan, program = self.planner.replan_program(
             n_devices, backend=self.backend)
-        # compile_program already validated; re-assert explicitly so the
-        # replan path cannot lose the check if compile defaults change,
-        # and re-run the per-device static analyzer — a replanned program
-        # for a shrunken ring is exactly where a schedule bug would
-        # surface first (exec/analysis; ``analyze="off"`` skips it).
-        validate_program(program, self.workload, cfg, backend=self.backend,
-                         analyze=None if self.analyze == "off"
-                         else self.analyze)
         self.program = program
         self._mesh = self._make_mesh(n_devices)
         # The façade re-derives residency for the survivor ring: the

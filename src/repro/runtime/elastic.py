@@ -36,12 +36,13 @@ class ElasticPlanner:
 
     def replan_program(self, n_devices: int, backend=None):
         """Degraded-mode replan: Lemma-1 plan on the surviving ring plus a
-        freshly compiled (and statically validated) period program for it.
+        freshly compiled (and checked) period program for it.
 
         Returns ``(cfg, plan, program)`` where ``cfg`` is the base config
-        shrunk to ``n_devices`` cores.  ``compile_program`` re-runs the
-        static verifier on the new schedule, so a bad replan is a hard
-        ``ProgramValidationError`` before anything executes.
+        shrunk to ``n_devices`` cores.  ``compile_program`` runs the
+        static checker (``exec.check``) on the new schedule, so a bad
+        replan is a hard ``ProgramValidationError`` before anything
+        executes.
         """
         from repro.core.planner import plan_fcnn, ring_mesh_axes
         from repro.exec.program import compile_program

@@ -1,6 +1,6 @@
-"""Static program verification (ISSUE 7: exec/validate.py) — every
-hand-corrupted program must be rejected with a precise error, every
-compiled program must pass."""
+"""Static program checking (exec/check), the rules a hand-corrupted
+program breaks — every such program must be rejected with a precise
+error, every compiled program must pass."""
 
 import dataclasses
 
@@ -14,7 +14,7 @@ from repro.exec.program import (
     Opcode,
     compile_fcnn_program,
 )
-from repro.exec.validate import ProgramValidationError, validate_program
+from repro.exec.check import ProgramValidationError, check_program
 
 N_DEV = 8
 W = workload("NN1", batch_size=8)
@@ -33,26 +33,26 @@ def _with_instrs(prog, instrs):
 @pytest.mark.parametrize("strategy", list(MappingStrategy))
 @pytest.mark.parametrize("backend", [None, ENoCBackend()])
 def test_compiled_programs_validate(strategy, backend):
-    """compile_* validates internally; re-validating externally (with the
+    """compile_* checks internally; checking again externally (with the
     full cost contract) must also pass for every strategy and backend."""
     p = compile_fcnn_program(W, CFG, N_DEV, strategy, backend=backend)
-    validate_program(p, W, CFG, backend=backend)
+    check_program(p, W, CFG, backend=backend)
 
 
 def test_rejects_dangling_recv(prog):
     instrs = [i for i in prog.instructions
               if not (i.opcode is Opcode.SEND and i.period == 2)]
     with pytest.raises(ProgramValidationError,
-                       match="dangling RECV at period 2: no matching SEND"):
-        validate_program(_with_instrs(prog, instrs))
+                       match="RECV period 2 on devices .*: no matching SEND"):
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_dangling_send(prog):
     instrs = [i for i in prog.instructions
               if not (i.opcode is Opcode.RECV and i.period == 2)]
     with pytest.raises(ProgramValidationError,
-                       match="dangling SEND at period 2"):
-        validate_program(_with_instrs(prog, instrs))
+                       match="SEND period 2 on devices .*: no matching RECV"):
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_out_of_mesh_window(prog):
@@ -63,7 +63,7 @@ def test_rejects_out_of_mesh_window(prog):
     instrs[idx] = bad
     with pytest.raises(ProgramValidationError,
                        match=r"outside the 8-device mesh"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_free_before_last_use(prog):
@@ -78,7 +78,7 @@ def test_rejects_free_before_last_use(prog):
             instrs.append(Instruction.FREE(period=p, released=(dev,)))
     with pytest.raises(ProgramValidationError,
                        match="freed before last use"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_non_divisor_degree(prog):
@@ -89,7 +89,7 @@ def test_rejects_non_divisor_degree(prog):
     instrs[idx] = dataclasses.replace(r, degree=3, devices=(0, 1, 2))
     with pytest.raises(ProgramValidationError,
                        match="degree 3 does not divide the device count 8"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_residency_leak(prog):
@@ -98,7 +98,7 @@ def test_rejects_residency_leak(prog):
                 if i.opcode is Opcode.FREE and i.period < 2 * W.l)
     instrs = [i for i in prog.instructions if i is not drop]
     with pytest.raises(ProgramValidationError, match="residency leak"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_cost_contract_violation(prog):
@@ -107,16 +107,16 @@ def test_rejects_cost_contract_violation(prog):
     instrs[idx] = dataclasses.replace(instrs[idx],
                                       cost_s=instrs[idx].cost_s * 2 + 1)
     bad = _with_instrs(prog, instrs)
-    validate_program(bad)        # structure-only: costs not checked
+    check_program(bad)           # no workload/cfg: costs not checked
     with pytest.raises(ProgramValidationError, match="simulator contract"):
-        validate_program(bad, W, CFG)
+        check_program(bad, W, CFG)
 
 
 def test_rejects_missing_run(prog):
     instrs = [i for i in prog.instructions
               if not (i.opcode is Opcode.RUN and i.period == 2)]
     with pytest.raises(ProgramValidationError, match="missing periods \\[2\\]"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_broken_bp_mirror(prog):
@@ -129,12 +129,12 @@ def test_rejects_broken_bp_mirror(prog):
     rotated = r.devices[1:] + r.devices[:1]
     instrs[idx] = dataclasses.replace(r, devices=rotated)
     with pytest.raises(ProgramValidationError, match="Eq. 11"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_run_on_non_resident_chunks(prog):
     """A RUN scheduled after its layer's param FREE touches freed chunks
-    (ISSUE 8 acceptance: validate_program rejects it)."""
+    (the checker's per-device memory walk rejects it)."""
     l = W.l
     instrs = list(prog.instructions)
     pf = next(i for i in instrs if i.opcode is Opcode.FREE and i.layer == 1)
@@ -142,8 +142,9 @@ def test_rejects_run_on_non_resident_chunks(prog):
     idx = next(k for k, i in enumerate(instrs)
                if i.opcode is Opcode.RUN and i.period == 2 * l)
     instrs.insert(idx, pf)
-    with pytest.raises(ProgramValidationError, match="non-resident"):
-        validate_program(_with_instrs(prog, instrs))
+    with pytest.raises(ProgramValidationError,
+                       match="use-after-FREE: RUN period 6 on device"):
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_param_free_byte_mismatch(prog):
@@ -156,7 +157,7 @@ def test_rejects_param_free_byte_mismatch(prog):
         instrs[idx], param_bytes=instrs[idx].param_bytes / 2)
     with pytest.raises(ProgramValidationError,
                        match="ledger would not drain"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_param_free_off_mirror_period(prog):
@@ -168,7 +169,7 @@ def test_rejects_param_free_off_mirror_period(prog):
     instrs.remove(pf)
     instrs.append(dataclasses.replace(pf, period=2 * l))
     with pytest.raises(ProgramValidationError, match="BP mirror period"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_missing_param_free(prog):
@@ -176,39 +177,35 @@ def test_rejects_missing_param_free(prog):
               if not (i.opcode is Opcode.FREE and i.layer == 1)]
     with pytest.raises(ProgramValidationError,
                        match="exactly one param FREE"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
 
 
 def test_rejects_param_bytes_geometry_mismatch(prog):
-    """A self-consistent but wrong byte ledger passes structurally and is
-    caught by the chunk-geometry check once workload+cfg are supplied."""
+    """A self-consistent but wrong byte ledger (every FREE drains what its
+    RUN holds) is caught by the chunk's element width: against the other
+    layers' without a config, against ``cfg.bytes_per_value`` with one."""
     instrs = []
     for i in prog.instructions:
         if i.layer == 1:          # FP RUN, BP RUN and param FREE of layer 1
             i = dataclasses.replace(i, param_bytes=i.param_bytes * 2)
         instrs.append(i)
     bad = _with_instrs(prog, instrs)
-    validate_program(bad)         # structure-only: ledger drains, passes
-    with pytest.raises(ProgramValidationError, match="chunk geometry"):
-        validate_program(bad, W, CFG)
+    with pytest.raises(ProgramValidationError,
+                       match="dtype mismatch: RUN period 2 .* period 1's"):
+        check_program(bad)
+    with pytest.raises(ProgramValidationError,
+                       match="dtype mismatch: RUN period 1 .* "
+                             "cfg.bytes_per_value"):
+        check_program(bad, W, CFG)
 
 
 def test_v1_programs_skip_residency_ledger(prog):
     """Schema-v1 programs (PR 6) have no residency annotations; the
-    ledger checks only apply from v2 on."""
+    residency rules only apply from v2 on."""
     instrs = [i for i in prog.instructions
               if not (i.opcode is Opcode.FREE and i.layer is not None)]
     with pytest.raises(ProgramValidationError,
                        match="exactly one param FREE"):
-        validate_program(_with_instrs(prog, instrs))
+        check_program(_with_instrs(prog, instrs))
     v1 = dataclasses.replace(_with_instrs(prog, instrs), version=1)
-    validate_program(v1)          # same instructions, v1: accepted
-
-
-def test_compile_program_validates_by_default():
-    """The compile path itself runs the verifier (validate=True default):
-    sabotaging the verifier's input via a monkeypatched compile would be
-    caught — here we just pin that a valid compile round-trips and that
-    validate=False is required to construct broken programs (used above)."""
-    p = compile_fcnn_program(W, CFG, N_DEV, "rrm")
-    validate_program(p, W, CFG)
+    check_program(v1)             # same instructions, v1: accepted

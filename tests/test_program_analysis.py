@@ -1,28 +1,32 @@
-"""Per-device static program analyzer (ISSUE 9): expansion, happens-before
-deadlock detection, chunk-level memory walk, shape abstract interpretation
-— and the seeded corruption corpus that ``validate_program`` passes but
-``analyze_program`` must reject with a precise error."""
+"""The per-device half of the static checker (exec/check): expansion,
+happens-before deadlock detection, chunk-level memory walk, shape rules
+— the seeded corruption corpus the checker must reject with a precise
+error, and the one place every compile and replan runs the checker."""
 
 import dataclasses
 import json
 
 import pytest
 
+import repro.exec.program as program_mod
+from repro.checkpoint import Checkpointer
 from repro.configs.nn_benchmarks import NN_BENCHMARKS, onoc_config, workload
 from repro.core.allocation import MappingStrategy
-from repro.exec.analysis import (
-    LEVELS,
+from repro.exec import check
+from repro.exec.check import (
     DeviceOp,
-    ProgramAnalysisError,
-    analyze_program,
-    check_memory,
+    ProgramValidationError,
+    check_program,
     corruption_corpus,
     expand_program,
     n_device_ops,
 )
+from repro.exec.check.hb import check_memory
 from repro.exec.program import Opcode, PeriodProgram, compile_fcnn_program
-from repro.exec.validate import ProgramValidationError, validate_program
 from repro.launch.mesh import make_test_mesh
+from repro.optim import adam
+from repro.runtime.degraded import DegradedModeRunner
+from repro.runtime.faults import FaultSchedule
 
 import repro.exec as rexec
 
@@ -46,48 +50,83 @@ def mesh():
 @pytest.mark.parametrize("name", sorted(NN_BENCHMARKS))
 def test_compiled_programs_analyze_clean(name, strategy, residency, mesh):
     """Acceptance sweep: every program produced by ``repro.exec.compile``
-    for NN1..NN6 x {fm,rrm,orrm} x {sharded,replicated} passes the full
-    analyzer (``analyze="full"`` is the compile default)."""
+    for NN1..NN6 x {fm,rrm,orrm} x {sharded,replicated} passes every
+    rule of the checker, the cost contract included."""
     w = workload(name, batch_size=8)
     exe = rexec.compile(w, CFG, mesh, strategy, residency=residency)
-    report = analyze_program(exe.program, w, CFG, level="full")
-    assert report.level == "full"
+    report = check_program(exe.program, w, CFG)
     assert report.n_devices == N_DEV
     assert report.n_instructions == len(exe.program.instructions)
-    assert report.checks == ("validate", "expand", "endpoints",
-                             "happens-before", "memory", "shapes")
+    assert report.checks == ("spmd", "happens-before", "memory", "shapes",
+                             "costs")
     assert report.n_hb_edges > report.n_device_ops > 0
-
-
-def test_analyze_levels():
-    assert analyze_program(PROG, level="off") is None
-    fast = analyze_program(PROG, level="fast")
-    assert "shapes" not in fast.checks
-    full = analyze_program(PROG, W, CFG, level="full")
-    assert "shapes" in full.checks
-    assert full.n_hb_edges == fast.n_hb_edges
-    with pytest.raises(ValueError, match="analyze level"):
-        analyze_program(PROG, level="bogus")
-    assert LEVELS == ("off", "fast", "full")
+    # without workload and cfg every rule but the cost contract runs
+    assert check_program(exe.program).checks == report.checks[:-1]
 
 
 def test_analysis_error_is_a_validation_error():
-    """One error taxonomy: handlers catching ProgramValidationError keep
-    working when the analyzer is switched on."""
-    assert issubclass(ProgramAnalysisError, ProgramValidationError)
+    """One error class: every rejection, the per-device ones included, is
+    a ``ProgramValidationError`` (a ``ValueError``)."""
+    assert issubclass(ProgramValidationError, ValueError)
+    for entry in CORPUS:
+        with pytest.raises(ProgramValidationError) as err:
+            check_program(entry.program, W, CFG)
+        assert type(err.value) is ProgramValidationError, entry.name
 
 
-def test_validate_program_delegates_to_analyzer():
-    validate_program(PROG, W, CFG, analyze="full")
-    corrupted = CORPUS[0].program
-    validate_program(corrupted, W, CFG)  # SPMD validator alone: blind
-    with pytest.raises(ProgramAnalysisError, match=CORPUS[0].match):
-        validate_program(corrupted, W, CFG, analyze="fast")
+def _count_checks(monkeypatch):
+    calls = []
+    real = check.check_program
+
+    def counting(*args, **kw):
+        calls.append(args[0])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(check, "check_program", counting)
+    return calls
 
 
-def test_compile_rejects_bad_analyze_level(mesh):
-    with pytest.raises(ValueError, match="analyze level"):
-        rexec.compile(W, CFG, mesh, "orrm", analyze="bogus")
+def test_compile_checks_the_program_exactly_once(mesh, monkeypatch):
+    calls = _count_checks(monkeypatch)
+    exe = rexec.compile(W, CFG, mesh, "orrm")
+    assert calls == [exe.program]
+
+
+def _degraded_runner(ckpt_dir):
+    return DegradedModeRunner(
+        workload=W, base_cfg=dataclasses.replace(CFG, m=N_DEV),
+        schedule=FaultSchedule(), checkpointer=Checkpointer(ckpt_dir),
+        optimizer=adam(1e-2), n_devices=N_DEV, kernel_mode="ref")
+
+
+def test_degraded_rebuild_checks_the_program_exactly_once(monkeypatch,
+                                                          tmp_path):
+    """A replan onto a shrunken ring checks its program once, inside
+    ``compile_program`` — the runner adds no second check."""
+    runner = _degraded_runner(tmp_path)
+    calls = _count_checks(monkeypatch)
+    runner._build(6)
+    assert calls == [runner.program]
+    assert runner.program.n_devices == 6
+
+
+@pytest.mark.parametrize("entry", CORPUS, ids=lambda e: e.name)
+def test_replan_emitting_a_corpus_entry_is_rejected(entry, monkeypatch,
+                                                    tmp_path):
+    """If the replan's ``compile_program`` emitted a corrupted program,
+    the rebuild raises before any mesh, executor or step exists."""
+    real = program_mod.PeriodProgram
+
+    def corrupting(**kw):
+        emitted = corruption_corpus(real(**kw), seed=0)
+        return next(e for e in emitted if e.name == entry.name).program
+
+    monkeypatch.setattr(program_mod, "PeriodProgram", corrupting)
+    runner = _degraded_runner(tmp_path)
+    with pytest.raises(ProgramValidationError, match=entry.match):
+        runner._build(N_DEV)
+    assert runner.program is None
+    assert runner.executable is None and runner._step_jit is None
 
 
 # -------------------------------------------------------------- expansion
@@ -142,18 +181,18 @@ def test_recv_sources_survive_json_roundtrip():
     back = PeriodProgram.from_json(json.loads(json.dumps(PROG.to_json())))
     for a, b in zip(PROG.instructions, back.instructions):
         assert a.sources == b.sources
-    analyze_program(back, W, CFG, level="full")
+    check_program(back, W, CFG)
 
 
 def test_recv_without_sources_derives_from_send():
     """Programs serialized before the ``sources`` annotation existed
-    still analyze: endpoints fall back to the same-period SEND window."""
+    still check: endpoints fall back to the same-period SEND window."""
     stripped = dataclasses.replace(PROG, instructions=tuple(
         dataclasses.replace(i, sources=())
         if i.opcode is Opcode.RECV else i
         for i in PROG.instructions))
-    report = analyze_program(stripped, W, CFG, level="full")
-    assert report is not None
+    report = check_program(stripped, W, CFG)
+    assert report.n_hb_edges > 0
 
 
 # ------------------------------------------------------ corruption corpus
@@ -172,29 +211,28 @@ def test_corpus_is_complete_and_deterministic():
 
 
 @pytest.mark.parametrize("entry", CORPUS, ids=lambda e: e.name)
-def test_corpus_passes_validator_but_analyzer_rejects(entry):
-    """The whole point of the corpus: each corruption sits in a blind
-    spot of the SPMD validator and only the per-device analyzer sees it."""
-    validate_program(entry.program, W, CFG)
-    with pytest.raises(ProgramAnalysisError, match=entry.match):
-        analyze_program(entry.program, W, CFG, level="full")
+def test_checker_rejects_corpus_entry(entry):
+    """Each corruption sits in a blind spot of the set-level rules; the
+    checker still rejects it, with the entry's message."""
+    with pytest.raises(ProgramValidationError, match=entry.match):
+        check_program(entry.program, W, CFG)
 
 
 @pytest.mark.parametrize(
     "entry",
     [e for e in CORPUS if not e.name.startswith("shape-")],
     ids=lambda e: e.name)
-def test_structural_corruptions_rejected_at_fast_level(entry):
-    """Deadlock/endpoint/memory corruptions need no workload: level
-    ``"fast"`` (no cost contract, no shape interpreter) catches them."""
-    with pytest.raises(ProgramAnalysisError, match=entry.match):
-        analyze_program(entry.program, level="fast")
+def test_structural_corruptions_rejected_without_workload(entry):
+    """Deadlock/endpoint/memory corruptions need no workload or config:
+    the checker rejects them from the program alone."""
+    with pytest.raises(ProgramValidationError, match=entry.match):
+        check_program(entry.program)
 
 
 def test_deadlock_message_names_the_cycle():
     entry = next(e for e in CORPUS if e.name == "deadlocked-send-cycle")
-    with pytest.raises(ProgramAnalysisError) as err:
-        analyze_program(entry.program, level="fast")
+    with pytest.raises(ProgramValidationError) as err:
+        check_program(entry.program)
     msg = str(err.value)
     assert "deadlock" in msg
     assert "RECV period" in msg and "SEND period" in msg  # cycle chain
@@ -203,8 +241,8 @@ def test_deadlock_message_names_the_cycle():
 
 def test_corpus_errors_name_device_and_period():
     for entry in CORPUS:
-        with pytest.raises(ProgramAnalysisError) as err:
-            analyze_program(entry.program, W, CFG, level="full")
+        with pytest.raises(ProgramValidationError) as err:
+            check_program(entry.program, W, CFG)
         assert "period" in str(err.value), entry.name
 
 
@@ -223,7 +261,7 @@ def test_check_memory_rejects_double_window_free():
         DeviceOp(device=0, index=2, op="free", period=1,
                  free_kind="window"),
     )
-    with pytest.raises(ProgramAnalysisError,
+    with pytest.raises(ProgramValidationError,
                        match="double FREE.*device 0.*freed at period 1"):
         check_memory({0: ops}, l=1, fp_windows={1: (0,)},
                      check_params=False)
@@ -232,11 +270,11 @@ def test_check_memory_rejects_double_window_free():
 def test_check_memory_rejects_param_double_free_and_leak():
     free = DeviceOp(device=0, index=2, op="free", period=2, layer=1,
                     free_kind="param")
-    with pytest.raises(ProgramAnalysisError, match="double FREE: param"):
+    with pytest.raises(ProgramValidationError, match="double FREE: param"):
         check_memory({0: (_run(0, 0, 1, 1), free,
                           dataclasses.replace(free, index=3, period=3))},
                      l=1, fp_windows={1: (0,)})
-    with pytest.raises(ProgramAnalysisError,
+    with pytest.raises(ProgramValidationError,
                        match="residency leak: device 0"):
         check_memory({0: (_run(0, 0, 1, 1),)}, l=1, fp_windows={1: (0,)})
 
@@ -248,6 +286,6 @@ def test_check_memory_rejects_run_after_param_free():
                  free_kind="param"),
         _run(0, 2, 2, 1, phase="bp"),
     )
-    with pytest.raises(ProgramAnalysisError,
+    with pytest.raises(ProgramValidationError,
                        match="use-after-FREE: RUN period 2"):
         check_memory({0: ops}, l=1, fp_windows={1: (0,)})
